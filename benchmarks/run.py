@@ -37,6 +37,8 @@ import argparse
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="short timing loops; skip the slowest benches")

@@ -10,10 +10,13 @@ round-off, and counts the collectives in the sharded jaxpr (halo traffic
 must lower to ``ppermute`` only; an ``all_gather`` would mean the plane
 was silently replicated).
 
-Multi-device CPU meshes need ``--xla_force_host_platform_device_count``
+On an accelerator the bench runs in-process on the real devices (a chip
+belongs to one process: a JAX child of a JAX parent could not reach it),
+and tilings that need more devices than the host has are skipped.  On the
+CPU, multi-device meshes need ``--xla_force_host_platform_device_count``
 set BEFORE jax initializes, and ``benchmarks.run`` has long since imported
-jax — so ``main()`` re-execs this module in a child process with the flag
-forced and the child writes the JSON.  Run standalone:
+jax — so there ``main()`` re-execs this module in a child process with the
+flag forced and the child writes the JSON.  Run standalone:
 
     PYTHONPATH=src python -m benchmarks.spatial_bench --emit BENCH_spatial.json
 
@@ -55,6 +58,10 @@ def _records(quick: bool) -> list[dict]:
         geom = CONVPLANE_SITES[site]
         batch = 1 if quick else geom["batch"]
         for dev_tiles in tilings[:1] if quick else tilings:
+            if dev_tiles[0] * dev_tiles[1] > jax.device_count():
+                print(f"{site}@{dev_tiles[0]}x{dev_tiles[1]}: skipped "
+                      f"({jax.device_count()} devices)", flush=True)
+                continue
             spec1 = convplane_spec(site, (1, 1))
             specd = convplane_spec(site, dev_tiles)
             plan1, pland = plan_conv(spec1), plan_conv(specd)
@@ -122,7 +129,12 @@ def child_main(quick: bool, json_path: str) -> None:
 
 
 def main(quick: bool = False, json_path: str | None = "BENCH_spatial.json"):
-    """Re-exec under the forced-device-count flag (parent entry point)."""
+    """Parent entry point: in-process on an accelerator, else re-exec under
+    the forced-device-count flag."""
+    import jax
+    if jax.default_backend() != "cpu":
+        child_main(quick, json_path or "")
+        return
     env = dict(os.environ)
     if "xla_force_host_platform_device_count" not in env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FLAG).strip()

@@ -23,9 +23,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import unet
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=8,
                     help="Euler denoising steps (CI smoke uses 2)")

@@ -11,9 +11,11 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 import tempfile                                       # noqa: E402
 
 from repro.launch.train import train                  # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     with tempfile.TemporaryDirectory() as d:
         losses, final = train(
             "llama3.2-1b", reduced=True, steps=30, batch=8, seq=64,

@@ -10,6 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import huge_conv_transpose2d, reference as ref
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # DCGAN DC2: 8x8x512 -> 16x16x256, 5x5 kernel, stride 2
 key = jax.random.PRNGKey(0)

@@ -20,9 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import segnet
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=1)
     ap.add_argument("--full", action="store_true",

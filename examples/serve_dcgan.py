@@ -40,6 +40,7 @@ from repro.models import gan
 from repro.runtime.fault import FailureInjector
 from repro.serving.control_plane import ControlPlane, ServeRequest
 from repro.serving.metrics import format_stats
+from repro.runtime.compile_cache import enable_compile_cache
 
 SMALL_LAYERS = (
     gan.DeconvLayer(4, 128, 64, 5, 2),
@@ -72,6 +73,7 @@ def drive(cp, payloads, *, rate, priority, slo_ms):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--rate", type=float, default=0.0,

@@ -10,9 +10,11 @@ import jax
 from repro.configs import registry
 from repro.models import transformer as tfm
 from repro.serving.batcher import ContinuousBatcher, Request
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = registry.get_reduced("llama3.2-1b")
     params, _ = tfm.init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)

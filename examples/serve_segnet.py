@@ -43,6 +43,7 @@ from repro.models import segnet
 from repro.runtime.fault import FailureInjector
 from repro.serving.control_plane import ControlPlane, ServeRequest
 from repro.serving.metrics import format_stats
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def build_control_plane(serve_fn, proto, *, max_wait_ms, cache, cache_key,
@@ -69,6 +70,7 @@ def drive(cp, payloads, *, rate, priority, slo_ms):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--rate", type=float, default=0.0,

@@ -16,6 +16,7 @@ import numpy as np
 from repro.models import gan
 from repro.models.gan import DeconvLayer
 from repro.train.data import GANPipeline
+from repro.runtime.compile_cache import enable_compile_cache
 
 # a reduced DCGAN (same family, CIFAR-scale 32x32 output) that trains in
 # minutes on one CPU core
@@ -27,6 +28,7 @@ SMALL_LAYERS = (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=16)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import vae
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def batch_at(cfg, batch: int, step: int, seed: int = 0) -> np.ndarray:
@@ -34,6 +35,7 @@ def batch_at(cfg, batch: int, step: int, seed: int = 0) -> np.ndarray:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=16)

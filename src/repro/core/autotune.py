@@ -19,7 +19,8 @@ This module is that measurement step:
   microbenchmarks and bench-time wall-clocks are the same code.
 - ``candidate_routes`` — the 2–4 feasible candidates the heuristic already
   enumerates for a (site, bucket): Pallas whole-plane and spatially tiled
-  variants (``pick_tiled_single`` / ``pick_tiled_transposed``),
+  variants (``plan.pallas_single_routes`` /
+  ``plan.pallas_transposed_routes``),
   ``fused_tap``, ``fused_plane``, ``taps``, and — transposed only — the
   ``per_phase`` executor as a first-class route.
 - ``measure_bucket``   — time every measurable candidate on the live
@@ -67,9 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import plan as planmod
-from repro.core.plan import (BATCH_BUCKETS, ConvPlan, ConvSpec, Route,
-                             pick_fused_tiles, pick_tiled_single,
-                             pick_tiled_transposed, pick_vmem_tiles)
+from repro.core.plan import BATCH_BUCKETS, ConvPlan, ConvSpec, Route
 
 SCHEMA = "huge2-route-cache/v1"
 CACHE_ENV = "HUGE2_ROUTE_CACHE"
@@ -407,12 +406,8 @@ def candidate_routes(plan: ConvPlan, batch: int) -> tuple[Route, ...]:
     the backward, not a tunable)."""
     spec = plan.spec
     itemsize = jnp.dtype(spec.dtype).itemsize
-    witemsize = planmod._weight_itemsize(spec)
-    c, n = spec.in_c, spec.out_c
+    n = spec.out_c
     oh, ow = plan.out_hw
-    want_pallas = spec.backend == "pallas" or (
-        spec.backend == "auto" and jax.default_backend() == "tpu")
-    cands: list[Route] = []
 
     if spec.kind == "transposed":
         if plan.total_taps == 0:
@@ -420,21 +415,9 @@ def candidate_routes(plan: ConvPlan, batch: int) -> tuple[Route, ...]:
         (glh, ghh), (glw, ghw) = plan.gpad
         hg = spec.in_hw[0] + glh + ghh
         wg = spec.in_hw[1] + glw + ghw
-        if want_pallas:
-            tiles = pick_fused_tiles(hg, wg, c, n, plan.total_taps,
-                                     plan.sum_uv, oh, ow, itemsize,
-                                     witemsize=witemsize)
-            if tiles is not None:
-                cands.append(Route(batch, "pallas", tiles))
-            if plan.uniform and oh % spec.strides[0] == 0 \
-                    and ow % spec.strides[1] == 0:
-                tiled = pick_tiled_transposed(c, n, plan.total_taps,
-                                              plan.phases, itemsize,
-                                              witemsize=witemsize)
-                if tiled is not None:
-                    c_t, n_t, sp = tiled
-                    cands.append(Route(batch, "pallas", (c_t, n_t),
-                                       sp_tiles=sp))
+        cands = planmod.pallas_transposed_routes(
+            spec, hg, wg, plan.out_hw, plan.total_taps, plan.sum_uv,
+            plan.uniform, plan.phases, itemsize, batch)
         ps = planmod._pixel_shuffle_route(spec, plan.phases, batch)
         if ps is not None:
             cands.append(ps)
@@ -452,20 +435,10 @@ def candidate_routes(plan: ConvPlan, batch: int) -> tuple[Route, ...]:
     hp = spec.in_hw[0] + ph[0] + ph[1]
     wp = spec.in_hw[1] + pw[0] + pw[1]
     r, s = spec.kernel_hw
-    fused_ok = (4 * batch * oh * ow * r * s * c
+    fused_ok = (4 * batch * oh * ow * r * s * spec.in_c
                 <= planmod._PLANE_BYTES_MAX)
-    if want_pallas:
-        tiles = pick_vmem_tiles(hp, wp, c, n, r, s, oh, ow, itemsize,
-                                witemsize=witemsize)
-        if tiles is not None:
-            cands.append(Route(batch, "pallas", tiles, fused_bwd=fused_ok))
-        dil = spec.dilation if spec.kind == "dilated" else (1, 1)
-        tiled = pick_tiled_single(c, n, r, s, oh, ow, spec.strides, dil,
-                                  itemsize, witemsize=witemsize)
-        if tiled is not None:
-            c_t, n_t, sp = tiled
-            cands.append(Route(batch, "pallas", (c_t, n_t),
-                               fused_bwd=fused_ok, sp_tiles=sp))
+    cands = planmod.pallas_single_routes(spec, hp, wp, plan.out_hw, itemsize,
+                                         batch, fused_ok)
     if fused_ok:
         cands.append(Route(batch, "fused_tap", None, fused_bwd=True))
     cands.append(Route(batch, "taps", None, fused_bwd=fused_ok))

@@ -102,6 +102,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
+import warnings
 from typing import Sequence
 
 import jax
@@ -112,8 +113,11 @@ from repro.core.untangle import pad_or_crop
 
 Pair = tuple[int, int]
 
-# leave headroom below the 16 MiB/core VMEM of v5e (moved from kernels.ops)
-_VMEM_BUDGET = 12 * 1024 * 1024
+# the Pallas tile searches fit the layout-exact working set
+# (``kernels.untangled_conv.vmem_bytes_estimate_*``) under this budget:
+# 8 MiB below the scoped-VMEM limit every kernel compiles with
+# (``VMEM_LIMIT_BYTES``), headroom for Mosaic's internal scratch
+_VMEM_BUDGET = 24 * 1024 * 1024
 
 # plan-time fuse heuristic for the per-phase fallback and plain convs:
 # concatenate tap views + one wide GEMM when the GEMM has too few rows to
@@ -158,38 +162,34 @@ def norm_padding(padding, k_hw) -> tuple[Pair, Pair]:
 
 
 def pick_vmem_tiles(hp, wp, c, n, r, s, oh, ow, itemsize, witemsize=None):
-    """Largest MXU-aligned (C_t, N_t) whose working set fits VMEM.
-
-    Plan-time replacement for the old per-call ``kernels.ops._pick_tiles``.
-    ``witemsize`` is the *weight* itemsize when it differs from the
-    activation's (int8 superpacks: 1 byte/elem + the f32 scale rows).
-    """
-    from repro.kernels.untangled_conv import vmem_bytes_estimate
-    for n_t in (256, 128, 64, 32, 16, 8):
-        for c_t in (256, 128, 64, 32, 16, 8):
-            if c_t > max(c, 8) * 2 or n_t > max(n, 8) * 2:
-                continue
-            if vmem_bytes_estimate(hp, wp, min(c_t, c), r, s, min(n_t, n),
-                                   oh, ow, itemsize,
-                                   witemsize=witemsize) <= _VMEM_BUDGET:
-                return min(c_t, c), min(n_t, n)
+    """(C_t, N_t) of the whole-plane single-correlation kernel — one lane
+    tile per channel dim (``lane_tile``) — or None when its working set
+    does not fit the VMEM budget.  ``witemsize`` is the *weight* itemsize
+    when it differs from the activation's (int8 superpacks: 1 byte/elem +
+    the f32 scale column)."""
+    from repro.kernels.untangled_conv import (lane_tile,
+                                              vmem_bytes_estimate_superpack)
+    c_t, n_t = lane_tile(c), lane_tile(n)
+    if vmem_bytes_estimate_superpack(hp, wp, c_t, r * s, n_t, oh, ow,
+                                     itemsize,
+                                     witemsize=witemsize) <= _VMEM_BUDGET:
+        return c_t, n_t
     return None
 
 
-def pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow, itemsize,
-                     witemsize=None):
-    """(C_t, N_t) for the multi-phase fused kernel: the working set is the
-    whole global plane + the superpack tile + per-phase f32 scratch + the
-    full interleaved output block."""
-    from repro.kernels.untangled_conv import vmem_bytes_estimate_fused
-    for n_t in (256, 128, 64, 32, 16, 8):
-        for c_t in (256, 128, 64, 32, 16, 8):
-            if c_t > max(c, 8) * 2 or n_t > max(n, 8) * 2:
-                continue
-            if vmem_bytes_estimate_fused(
-                    hg, wg, min(c_t, c), total_taps, min(n_t, n), sum_uv,
-                    oh, ow, itemsize, witemsize=witemsize) <= _VMEM_BUDGET:
-                return min(c_t, c), min(n_t, n)
+def pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow, tap_rows,
+                     itemsize, witemsize=None):
+    """(C_t, N_t) for the multi-phase fused kernel, or None: the working
+    set is the whole global plane + the superpack tile + per-phase f32
+    scratch + the full interleaved output block + the largest phase's
+    ``tap_rows``-row tap GEMM."""
+    from repro.kernels.untangled_conv import (lane_tile,
+                                              vmem_bytes_estimate_fused)
+    c_t, n_t = lane_tile(c), lane_tile(n)
+    if vmem_bytes_estimate_fused(hg, wg, c_t, total_taps, n_t, sum_uv,
+                                 oh, ow, tap_rows, itemsize,
+                                 witemsize=witemsize) <= _VMEM_BUDGET:
+        return c_t, n_t
     return None
 
 
@@ -201,55 +201,51 @@ def _spatial_cands(extent: int) -> tuple[int, ...]:
 def pick_tiled_single(c, n, r, s, oh, ow, strides, dilation, itemsize,
                       witemsize=None):
     """(C_t, N_t, (T_oh, T_ow)) for the spatially tiled single-correlation
-    kernel, or None.  N tiles are maximized *first*: every N-tile revisit
-    re-streams the full halo'd C range of the tile (total halo DMA per
-    plane is ∝ N/N_t and independent of C_t), so a big N_t minimizes DMA
-    traffic; then the largest C_t (fewer accumulator carries, fatter MXU
-    contractions), then the largest output tile whose double-buffered
-    working set (``vmem_bytes_estimate_tiled``) fits the budget."""
-    from repro.kernels.untangled_conv import (halo_extent,
+    kernel, or None: ``C_t`` is a whole lane tile (the DMA'd halo slice
+    needs a lane-dense channel dim, so narrower planes are zero-padded up
+    to it), ``N_t`` a lane tile, and the output tile the largest whose
+    double-buffered working set (``vmem_bytes_estimate_tiled``) fits the
+    budget."""
+    from repro.kernels.untangled_conv import (LANES, halo_extent, lane_tile,
                                               vmem_bytes_estimate_tiled)
     (sh, sw), (dh, dw) = strides, dilation
-    for n_t in (256, 128, 64, 32, 16, 8):
-        for c_t in (256, 128, 64, 32, 16, 8):
-            if c_t > max(c, 8) * 2 or n_t > max(n, 8) * 2:
-                continue
-            for toh in _spatial_cands(oh):
-                for tow in _spatial_cands(ow):
-                    tin_h = halo_extent(toh, r, sh, dh)
-                    tin_w = halo_extent(tow, s, sw, dw)
-                    if vmem_bytes_estimate_tiled(
-                            tin_h, tin_w, min(c_t, c), r * s, min(n_t, n),
-                            toh * tow, itemsize,
-                            witemsize=witemsize) <= _VMEM_BUDGET:
-                        return min(c_t, c), min(n_t, n), (toh, tow)
+    c_t, n_t = LANES, lane_tile(n)
+    for toh in _spatial_cands(oh):
+        for tow in _spatial_cands(ow):
+            tin_h = halo_extent(toh, r, sh, dh)
+            tin_w = halo_extent(tow, s, sw, dw)
+            if vmem_bytes_estimate_tiled(
+                    tin_h, tin_w, c_t, r * s, n_t, (toh, tow), toh * tow,
+                    toh * tow, itemsize,
+                    witemsize=witemsize) <= _VMEM_BUDGET:
+                return c_t, n_t, (toh, tow)
     return None
 
 
-def pick_tiled_transposed(c, n, total_taps, phases, itemsize, witemsize=None):
+def pick_tiled_transposed(c, n, total_taps, phases, strides, itemsize,
+                          witemsize=None):
     """(C_t, N_t, (T_u, T_v)) for the spatially tiled multi-phase deconv
     kernel, or None.  Tile sizes are in *phase-output* coordinates (the
     interleaved output tile is (T_u·s_h, T_v·s_w)); the halo covers the
-    phase tap-origin span, so it is phase-aware by construction.  Search
-    order as in ``pick_tiled_single``: N_t (DMA), then C_t, then space.
+    phase tap-origin span, so it is phase-aware by construction.  Channel
+    tiles as in ``pick_tiled_single``; the largest spatial tile that fits.
     Only uniform-phase plans call this (checked by the route builder)."""
-    from repro.kernels.untangled_conv import (deconv_tap_span,
+    from repro.kernels.untangled_conv import (LANES, deconv_tap_span,
+                                              lane_tile,
                                               vmem_bytes_estimate_tiled)
     uu, vv = phases[0].out_hw
+    (sh, sw) = strides
     ((mh, xh_max), (mw, xw_max)) = deconv_tap_span(phases)
-    for n_t in (256, 128, 64, 32, 16, 8):
-        for c_t in (256, 128, 64, 32, 16, 8):
-            if c_t > max(c, 8) * 2 or n_t > max(n, 8) * 2:
-                continue
-            for tu in _spatial_cands(uu):
-                for tv in _spatial_cands(vv):
-                    tin_h = xh_max - mh + tu
-                    tin_w = xw_max - mw + tv
-                    if vmem_bytes_estimate_tiled(
-                            tin_h, tin_w, min(c_t, c), total_taps,
-                            min(n_t, n), len(phases) * tu * tv,
-                            itemsize, witemsize=witemsize) <= _VMEM_BUDGET:
-                        return min(c_t, c), min(n_t, n), (tu, tv)
+    c_t, n_t = LANES, lane_tile(n)
+    for tu in _spatial_cands(uu):
+        for tv in _spatial_cands(vv):
+            tin_h = xh_max - mh + tu
+            tin_w = xw_max - mw + tv
+            if vmem_bytes_estimate_tiled(
+                    tin_h, tin_w, c_t, total_taps, n_t, (tu * sh, tv * sw),
+                    len(phases) * tu * tv, tu * tv, itemsize,
+                    witemsize=witemsize) <= _VMEM_BUDGET:
+                return c_t, n_t, (tu, tv)
     return None
 
 
@@ -386,9 +382,8 @@ def _choose_path(backend: str, hp: int, wp: int, c: int, n: int,
     u, v = out_hw
     if th == 0 or tw == 0 or u == 0 or v == 0:
         return "zeros", None
-    want_pallas = backend == "pallas" or (
-        backend == "auto" and jax.default_backend() == "tpu")
-    if want_pallas:
+    if backend == "pallas" or (backend == "auto"
+                               and jax.default_backend() == "tpu"):
         tiles = pick_vmem_tiles(hp, wp, c, n, th, tw, u, v, itemsize)
         if tiles is not None:
             return "pallas", tiles
@@ -479,34 +474,75 @@ def _single_route_1dev(spec: ConvSpec, hp: int, wp: int, out_hw: Pair,
     im2col-sized layout) and grows linearly in the bucket, so big buckets
     route to 'taps' where small ones fuse."""
     r, s = spec.kernel_hw
-    c, n = spec.in_c, spec.out_c
+    c = spec.in_c
     oh, ow = out_hw
     # tap-stack blowup vs the resident plane: B*oh*ow*R*S rows of C against
     # B*hp*wp plane rows; cap the materialized f32 buffer.  The backward's
     # dy-GEMM / stacked-dK buffers are the same size, so one cap governs
     # both directions of the bucket.
     fused_ok = 4 * batch * oh * ow * r * s * c <= _PLANE_BYTES_MAX
-    want_pallas = spec.backend == "pallas" or (
-        spec.backend == "auto" and jax.default_backend() == "tpu")
-    witemsize = _weight_itemsize(spec)
-    if want_pallas:
-        # the 'pallas' verdict is a *tile*-fits check: whole-plane residency
-        # when it fits (no halo waste), else spatial output tiling — plane
-        # size alone never pushes a site off the Pallas route
-        tiles = pick_vmem_tiles(hp, wp, c, n, r, s, oh, ow, itemsize,
-                                witemsize=witemsize)
-        if tiles is not None:
-            return Route(batch, "pallas", tiles, fused_bwd=fused_ok)
-        dil = spec.dilation if spec.kind == "dilated" else (1, 1)
-        tiled = pick_tiled_single(c, n, r, s, oh, ow, spec.strides, dil,
-                                  itemsize, witemsize=witemsize)
-        if tiled is not None:
-            c_t, n_t, sp = tiled
-            return Route(batch, "pallas", (c_t, n_t), fused_bwd=fused_ok,
-                         sp_tiles=sp)
+    pallas = pallas_single_routes(spec, hp, wp, out_hw, itemsize, batch,
+                                  fused_ok)
+    if pallas:
+        return pallas[0]
     if fused_ok:
         return Route(batch, "fused_tap", None, fused_bwd=True)
     return Route(batch, "taps", None, fused_bwd=False)
+
+
+def want_pallas(spec: ConvSpec) -> bool:
+    """Does the spec's backend policy ask for the Pallas kernels?"""
+    return spec.backend == "pallas" or (
+        spec.backend == "auto" and jax.default_backend() == "tpu")
+
+
+# specs whose Pallas request already fell through to an XLA route (warned
+# once per process, like ``spatial._INFEASIBLE_WARNED``)
+_OFF_PALLAS_WARNED: set = set()
+
+
+def _warn_off_pallas(spec: ConvSpec, batch: int, why: str) -> None:
+    """A spec that asks for Pallas but gets no compilable Pallas route at
+    some bucket runs an XLA route there — say so, once per spec."""
+    if spec in _OFF_PALLAS_WARNED:
+        return
+    _OFF_PALLAS_WARNED.add(spec)
+    warnings.warn(
+        f"plan_conv: {spec.kind} site {spec.in_hw}x{spec.in_c}->"
+        f"{spec.out_c} k={spec.kernel_hw} s={spec.strides} asks for the "
+        f"Pallas kernels but bucket B={batch} runs an XLA route ({why})",
+        RuntimeWarning, stacklevel=4)
+
+
+def pallas_single_routes(spec: ConvSpec, hp: int, wp: int, out_hw: Pair,
+                         itemsize: int, batch: int,
+                         fused_bwd: bool) -> list[Route]:
+    """Every Pallas route of a single-correlation bucket, preferred first:
+    whole-plane residency when its tiles fit (no halo waste), then the
+    spatially tiled kernel — plane size alone never pushes a site off the
+    Pallas route.  Empty unless the spec asks for Pallas (warned when
+    nothing fits)."""
+    if not want_pallas(spec):
+        return []
+    r, s = spec.kernel_hw
+    c, n = spec.in_c, spec.out_c
+    oh, ow = out_hw
+    witemsize = _weight_itemsize(spec)
+    routes = []
+    tiles = pick_vmem_tiles(hp, wp, c, n, r, s, oh, ow, itemsize,
+                            witemsize=witemsize)
+    if tiles is not None:
+        routes.append(Route(batch, "pallas", tiles, fused_bwd=fused_bwd))
+    dil = spec.dilation if spec.kind == "dilated" else (1, 1)
+    tiled = pick_tiled_single(c, n, r, s, oh, ow, spec.strides, dil,
+                              itemsize, witemsize=witemsize)
+    if tiled is not None:
+        c_t, n_t, sp = tiled
+        routes.append(Route(batch, "pallas", (c_t, n_t), fused_bwd=fused_bwd,
+                            sp_tiles=sp))
+    if not routes:
+        _warn_off_pallas(spec, batch, "no halo tile fits the VMEM budget")
+    return routes
 
 
 def _pixel_shuffle_geom(spec: ConvSpec, phases) -> tuple[Pair, tuple[Pair, Pair]] | None:
@@ -569,27 +605,15 @@ def _transposed_route_1dev(spec: ConvSpec, hg: int, wg: int, out_hw: Pair,
     """Whole-conv route for the transposed kind at one batch bucket: one
     launch / one wide GEMM, the plane-GEMM intermediate capped at the
     bucket's size."""
-    c, n = spec.in_c, spec.out_c
-    oh, ow = out_hw
+    n = spec.out_c
     if total_taps == 0:
         # every phase is empty; executor emits zeros
         return Route(batch, "taps", None)
-    want_pallas = spec.backend == "pallas" or (
-        spec.backend == "auto" and jax.default_backend() == "tpu")
-    witemsize = _weight_itemsize(spec)
-    if want_pallas:
-        tiles = pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow,
-                                 itemsize, witemsize=witemsize)
-        if tiles is not None:
-            return Route(batch, "pallas", tiles)
-        # big planes: spatially tiled kernel (uniform phases — equivalently
-        # out % stride == 0 — so the interleaved output tiles block cleanly)
-        if uniform and oh % spec.strides[0] == 0 and ow % spec.strides[1] == 0:
-            tiled = pick_tiled_transposed(c, n, total_taps, phases, itemsize,
-                                          witemsize=witemsize)
-            if tiled is not None:
-                c_t, n_t, sp = tiled
-                return Route(batch, "pallas", (c_t, n_t), sp_tiles=sp)
+    pallas = pallas_transposed_routes(spec, hg, wg, out_hw, total_taps,
+                                      sum_uv, uniform, phases, itemsize,
+                                      batch)
+    if pallas:
+        return pallas[0]
     # sub-pixel rewrite ahead of the fused routes: exact FLOPs like
     # fused_tap but a Q×-smaller GEMM buffer, and no plane-GEMM blowup
     ps = _pixel_shuffle_route(spec, phases, batch)
@@ -602,6 +626,41 @@ def _transposed_route_1dev(spec: ConvSpec, hg: int, wg: int, out_hw: Pair,
     if uniform:
         return Route(batch, "fused_tap", None)
     return Route(batch, "taps", None)
+
+
+def pallas_transposed_routes(spec: ConvSpec, hg: int, wg: int, out_hw: Pair,
+                             total_taps: int, sum_uv: int, uniform: bool,
+                             phases, itemsize: int,
+                             batch: int) -> list[Route]:
+    """Every Pallas route of a transposed bucket, preferred first: the
+    whole-plane fused kernel when it fits, then the spatially tiled one
+    (uniform phases only — equivalently out % stride == 0 — so the
+    interleaved output tiles block cleanly).  Empty unless the spec asks
+    for Pallas (warned when nothing fits)."""
+    if not want_pallas(spec):
+        return []
+    c, n = spec.in_c, spec.out_c
+    oh, ow = out_hw
+    witemsize = _weight_itemsize(spec)
+    tap_rows = max(ex.out_hw[0] * ex.out_hw[1] for ex in phases)
+    routes = []
+    tiles = pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow,
+                             tap_rows, itemsize, witemsize=witemsize)
+    if tiles is not None:
+        routes.append(Route(batch, "pallas", tiles))
+    tileable = (uniform and oh % spec.strides[0] == 0
+                and ow % spec.strides[1] == 0)
+    if tileable:
+        tiled = pick_tiled_transposed(c, n, total_taps, phases, spec.strides,
+                                      itemsize, witemsize=witemsize)
+        if tiled is not None:
+            c_t, n_t, sp = tiled
+            routes.append(Route(batch, "pallas", (c_t, n_t), sp_tiles=sp))
+    if not routes:
+        _warn_off_pallas(spec, batch, "the whole plane does not fit the VMEM "
+                         "budget" + ("" if tileable else " and non-uniform "
+                                     "phases cannot be spatially tiled"))
+    return routes
 
 
 def _route_exact(plan: "ConvPlan", batch: int) -> Route:

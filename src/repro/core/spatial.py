@@ -68,7 +68,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import decompose as dec
 from repro.core.plan import ConvSpec, Route, plan_conv
-from repro.sharding import shard_map_compat
 
 Pair = tuple[int, int]
 
@@ -308,16 +307,14 @@ def _exchange(xb, axis: int, mesh_axis: str, dim: DimTiling):
     return out
 
 
-def spatial_apply(sp: SpatialPlan, x4: jax.Array, packed: jax.Array,
-                  mesh, axes: Pair = SPATIAL_AXES) -> jax.Array:
-    """Run the planned conv plane-parallel over ``mesh``: pad the plane to
-    the device-aligned extent, shard rows/cols over the spatial axes,
-    exchange halos (rows, then columns of the row-extended slab), run the
-    local plan's single-device executor per shard, reassemble, slice.
-
-    Differentiable end to end: the local plan's custom VJP runs per shard
-    inside the ``shard_map``, whose transpose reverses the ``ppermute``
-    halo flows and psums the replicated superpack's cotangent."""
+def spatial_apply_padded(sp: SpatialPlan, x4: jax.Array,
+                         packed: jax.Array, mesh,
+                         axes: Pair = SPATIAL_AXES) -> jax.Array:
+    """The plane-parallel launch itself: pad the plane to the
+    device-aligned extent, shard rows/cols over the spatial axes, exchange
+    halos (rows, then columns of the row-extended slab) and run the local
+    plan's single-device executor per shard.  Returns the device-aligned
+    ``(B, OH', OW', N)`` output, still sharded one block per device."""
     th, tw = sp.dims
     ax_h, ax_w = axes
     lplan = plan_conv(sp.local_spec)
@@ -332,11 +329,27 @@ def spatial_apply(sp: SpatialPlan, x4: jax.Array, packed: jax.Array,
 
     spec_h = ax_h if th.dev > 1 else None
     spec_w = ax_w if tw.dev > 1 else None
-    f = shard_map_compat(
-        body, mesh,
+    # the replication check is off: the bodies return device-varying
+    # tiles and psum the weight cotangent through the transpose
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(None, spec_h, spec_w, None), P(None, None)),
-        out_specs=P(None, spec_h, spec_w, None))
-    y = f(x4, packed)
+        out_specs=P(None, spec_h, spec_w, None), check_vma=False)
+    return f(x4, packed)
+
+
+def spatial_apply(sp: SpatialPlan, x4: jax.Array, packed: jax.Array,
+                  mesh, axes: Pair = SPATIAL_AXES) -> jax.Array:
+    """Run the planned conv plane-parallel over ``mesh``
+    (``spatial_apply_padded``) and slice the device-aligned output back to
+    the conv's extent.  That slice stays on the devices when the extent
+    divides over them; an extent that does not (a 385-px plane over 2) has
+    no even sharding, so the compiler gathers the sliced plane.
+
+    Differentiable end to end: the local plan's custom VJP runs per shard
+    inside the ``shard_map``, whose transpose reverses the ``ppermute``
+    halo flows and psums the replicated superpack's cotangent."""
+    y = spatial_apply_padded(sp, x4, packed, mesh, axes)
     oh, ow = sp.out_hw
     if y.shape[1] != oh or y.shape[2] != ow:
         y = y[:, :oh, :ow, :]

@@ -16,10 +16,15 @@ TPU mapping decisions (the HUGE2 "cache locality" story, restated for VMEM/MXU):
   tile feeding the MXU with N on the lane axis — the TPU analogue of the
   paper's C×N×R×S coalescing layout.  Strided and dilated correlations run
   the *same* kernel; dilation only moves each tap's read origin inside the
-  resident plane (no zero-inserted kernel exists anywhere).
-* taps are a *static* unrolled loop of MXU matmuls with an f32 VMEM
-  accumulator; the C grid axis is innermost-sequential so the accumulator
-  carries across C tiles (revisiting semantics).
+  resident plane (no zero-inserted kernel exists anywhere).  A strided tap
+  is a **strided ref load** (``pl.ds(start, size, stride)`` on the H and W
+  dims of the VMEM block), never a strided value slice.
+* taps are a *static* unrolled loop of MXU matmuls accumulating straight
+  into an f32 VMEM scratch; the C grid axis is innermost-sequential so the
+  accumulator carries across C tiles (revisiting semantics).
+* channel tiles are one lane tile: ``C_t = min(C, 128)`` and ``N_t = min(N,
+  128)`` (``lane_tile``).  128 is the v5e MXU width, and Mosaic's strided
+  loads and stores accept a last dim of at most one lane tile.
 
 ``_deconv_kernel`` extends the same mapping to the *fused* transposed conv:
 ONE launch computes every s_h*s_w output phase over a single VMEM residency
@@ -36,21 +41,24 @@ Grid: ``(B, N/N_t, C/C_t)`` — C innermost (reduction).
 whole padded plane does not fit VMEM, the grid grows ``(oh_tiles, ow_tiles)``
 axes — ``(B, OH/T_oh, OW/T_ow, N/N_t, C/C_t)``, C still innermost — and the
 kernel computes one **halo'd output tile** per step.  The input stays whole
-in ``pltpu.ANY`` (compiler-placed, HBM for big planes) and each step's
+in ``pl.ANY`` (compiler-placed, HBM for big planes) and each step's
 halo'd input slice — output-tile footprint plus the stride/dilation-aware
 tap reach ``(T-1)·d`` (phase-aware tap-origin span for the multi-phase
 deconv) — is fetched by an explicit **double-buffered DMA**: the next
 step's halo slice streams into the other slot while the MXU runs the
 current tap loop.  Per-output-pixel accumulation order (tap-major inside a
-C tile, C tiles outer) is identical to the whole-plane kernels, so tiled
-and untiled outputs are bit-compatible.  Plane size alone never pushes a
-site off the Pallas route (the plan layer keeps XLA fallbacks only for
-non-uniform-phase transposed shapes and halos beyond the VMEM budget).
+C tile, C tiles outer) is the same as the whole-plane kernels'.  Plane size
+alone never pushes a site off the Pallas route (the plan layer keeps XLA
+fallbacks only for non-uniform-phase transposed shapes and halos beyond
+the VMEM budget).
+
+Every launch compiles under an explicit scoped-VMEM limit
+(``VMEM_LIMIT_BYTES``); the plan layer sizes tiles against the layout-exact
+working set (``vmem_bytes_estimate_*``) with headroom below that limit.
 """
 from __future__ import annotations
 
 import functools
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +66,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 Pair = tuple[int, int]
+
+# lanes of one vreg: the channel tile of every route (``lane_tile``)
+LANES = 128
+
+# Mosaic's scoped-VMEM limit for every launch here.  A v5e core has
+# 128 MiB of VMEM; the compiler's default scope is 16 MiB, which the
+# double-buffered whole-plane blocks of real decoder layers outgrow.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def lane_tile(d: int) -> int:
+    """The (C_t or N_t) tile of a channel dim: one 128-lane tile, or the
+    whole dim when it is narrower — the only tiles Mosaic lays out without
+    a lane-misaligned slice."""
+    return min(d, LANES)
 
 
 def _tap_panel(k_ref, s_ref, t: int):
@@ -74,6 +99,25 @@ def _tap_panel(k_ref, s_ref, t: int):
     return panel.astype(jnp.float32) * s_ref[t]
 
 
+def _tap_dot(acc_ref, rows, xs, k_ref, s_ref, t: int):
+    """``acc[rows] += xs @ panel_t`` — one tap's MXU product, f32."""
+    acc_ref[rows, :] += jnp.dot(xs.reshape(-1, xs.shape[-1]),
+                                _tap_panel(k_ref, s_ref, t),
+                                preferred_element_type=jnp.float32)
+
+
+def _single_taps(load, acc_ref, k_ref, s_ref, taps_hw: Pair,
+                 dilation: Pair):
+    """The single-correlation tap loop: tap ``t = m·S + n`` reads
+    ``load(m·d_h, n·d_w)`` (a strided window of the resident plane)."""
+    r, s = taps_hw
+    dh, dw = dilation
+    for m in range(r):                 # static tap unroll -> MXU matmul chain
+        for n in range(s):
+            _tap_dot(acc_ref, slice(None), load(m * dh, n * dw), k_ref,
+                     s_ref, m * s + n)
+
+
 def _kernel(x_ref, k_ref, *rest, taps_hw: Pair, strides: Pair,
             dilation: Pair, out_hw: Pair, n_c_tiles: int):
     """Single-correlation kernel over the tap-major superpack: ``k_ref`` is
@@ -83,9 +127,7 @@ def _kernel(x_ref, k_ref, *rest, taps_hw: Pair, strides: Pair,
     origin inside the resident plane).  An int8 superpack rides with a third
     input ref of per-tap-row scales (see ``_tap_panel``)."""
     s_ref, o_ref, acc_ref = rest if len(rest) == 3 else (None, *rest)
-    r, s = taps_hw
     sh, sw = strides
-    dh, dw = dilation
     oh, ow = out_hw
     ci = pl.program_id(2)
 
@@ -93,40 +135,32 @@ def _kernel(x_ref, k_ref, *rest, taps_hw: Pair, strides: Pair,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0]                       # (Hp, Wp, C_t) resident in VMEM
-    acc = acc_ref[...]
-    for m in range(r):                 # static tap unroll -> MXU matmul chain
-        for n in range(s):
-            xs = jax.lax.slice(
-                x, (m * dh, n * dw, 0),
-                (m * dh + (oh - 1) * sh + 1, n * dw + (ow - 1) * sw + 1,
-                 x.shape[2]),
-                (sh, sw, 1))
-            acc += jnp.dot(xs.reshape(oh * ow, xs.shape[2]),
-                           _tap_panel(k_ref, s_ref, m * s + n),
-                           preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
+    _single_taps(
+        lambda i, j: x_ref[0, pl.ds(i, oh, sh), pl.ds(j, ow, sw), :],
+        acc_ref, k_ref, s_ref, taps_hw, dilation)
 
     @pl.when(ci == n_c_tiles - 1)
     def _flush():
-        o_ref[0] = acc.reshape(oh, ow, acc.shape[-1]).astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].reshape(oh, ow, -1).astype(o_ref.dtype)
 
 
 def _halo_stream(x_any, buf, sem, origin):
     """Double-buffered halo'd-tile fetch shared by both tiled kernels.
 
     ``origin(i, j)`` maps a spatial tile index to the slice origin (rows,
-    cols) inside the ``pltpu.ANY``-resident plane; the channel slice comes
-    from the innermost grid axis.  Ravels the ``(b, i, j, n, c)`` grid into
-    a linear step (the halo slice depends on everything but the N tile),
-    starts the *next* step's DMA into the other slot so it streams while
-    the caller's MXU loop runs, then waits on and returns the current
-    step's tile (a ``(tin_h, tin_w, C_t)`` VMEM view)."""
+    cols) inside the ``pl.ANY``-resident plane; the channel slice comes
+    from the innermost grid axis (the whole channel dim when one C tile
+    covers it — a sub-lane-tile channel slice is not DMA-able).  Ravels the
+    ``(b, i, j, n, c)`` grid into a linear step (the halo slice depends on
+    everything but the N tile), starts the *next* step's DMA into the other
+    slot so it streams while the caller's MXU loop runs, then waits on the
+    current step's tile and returns its slot in ``buf``."""
     bi, oi, oj, ni, ci = (pl.program_id(d) for d in range(5))
     nb, n_oi, n_oj, nn, nc = (pl.num_programs(d) for d in range(5))
     step = (((bi * n_oi + oi) * n_oj + oj) * nn + ni) * nc + ci
     total = nb * n_oi * n_oj * nn * nc
     _, tin_h, tin_w, c_t = buf.shape
+    whole_c = c_t == x_any.shape[-1]
 
     def tile_dma(slot, st):
         c_ = jax.lax.rem(st, nc)
@@ -136,9 +170,9 @@ def _halo_stream(x_any, buf, sem, origin):
         i_ = jax.lax.rem(st, n_oi)
         b_ = jax.lax.div(st, n_oi)
         r0, c0 = origin(i_, j_)
+        chans = slice(None) if whole_c else pl.ds(c_ * c_t, c_t)
         return pltpu.make_async_copy(
-            x_any.at[b_, pl.ds(r0, tin_h), pl.ds(c0, tin_w),
-                     pl.ds(c_ * c_t, c_t)],
+            x_any.at[b_, pl.ds(r0, tin_h), pl.ds(c0, tin_w), chans],
             buf.at[slot], sem.at[slot])
 
     slot = jax.lax.rem(step, 2)
@@ -152,23 +186,20 @@ def _halo_stream(x_any, buf, sem, origin):
         tile_dma(jax.lax.rem(step + 1, 2), step + 1).start()
 
     tile_dma(slot, step).wait()
-    return buf[slot]
+    return slot
 
 
 def _tiled_kernel(x_any, k_ref, *rest, taps_hw: Pair,
                   strides: Pair, dilation: Pair, tile_hw: Pair,
                   n_c_tiles: int):
     """Spatially tiled single-correlation kernel: one halo'd output tile per
-    grid step, the input whole in ``pltpu.ANY`` and each step's halo slice
+    grid step, the input whole in ``pl.ANY`` and each step's halo slice
     DMA'd into a double-buffered VMEM scratch (the next slice streams while
     the MXU runs the current tap loop).  Tap/C-tile accumulation order is
-    identical to ``_kernel``, so the output is bit-compatible with the
-    whole-plane route."""
+    the same as ``_kernel``'s."""
     s_ref, o_ref, buf, sem, acc_ref = \
         rest if len(rest) == 5 else (None, *rest)
-    r, s = taps_hw
     sh, sw = strides
-    dh, dw = dilation
     toh, tow = tile_hw
     ci = pl.program_id(4)
 
@@ -176,24 +207,15 @@ def _tiled_kernel(x_any, k_ref, *rest, taps_hw: Pair,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = _halo_stream(x_any, buf, sem,
-                     lambda i_, j_: (i_ * toh * sh, j_ * tow * sw))
-    acc = acc_ref[...]
-    for m in range(r):                  # static tap unroll -> MXU matmuls
-        for n in range(s):
-            xs = jax.lax.slice(
-                x, (m * dh, n * dw, 0),
-                (m * dh + (toh - 1) * sh + 1, n * dw + (tow - 1) * sw + 1,
-                 x.shape[2]),
-                (sh, sw, 1))
-            acc += jnp.dot(xs.reshape(toh * tow, xs.shape[2]),
-                           _tap_panel(k_ref, s_ref, m * s + n),
-                           preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
+    slot = _halo_stream(x_any, buf, sem,
+                        lambda i_, j_: (i_ * toh * sh, j_ * tow * sw))
+    _single_taps(
+        lambda i, j: buf[slot, pl.ds(i, toh, sh), pl.ds(j, tow, sw), :],
+        acc_ref, k_ref, s_ref, taps_hw, dilation)
 
     @pl.when(ci == n_c_tiles - 1)
     def _flush():
-        o_ref[0] = acc.reshape(toh, tow, acc.shape[-1]).astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].reshape(toh, tow, -1).astype(o_ref.dtype)
 
 
 def halo_extent(tile: int, taps: int, stride: int, dilation: int) -> int:
@@ -218,7 +240,8 @@ def untangled_conv2d_superpack_pallas(x: jax.Array, superpack: jax.Array, *,
                                       strides: Pair = (1, 1),
                                       rhs_dilation: Pair = (1, 1),
                                       scales: jax.Array | None = None,
-                                      c_tile: int = 128, n_tile: int = 128,
+                                      c_tile: int = LANES,
+                                      n_tile: int = LANES,
                                       sp_tiles: Pair | None = None,
                                       out_dtype=None,
                                       interpret: bool | None = None
@@ -231,7 +254,9 @@ def untangled_conv2d_superpack_pallas(x: jax.Array, superpack: jax.Array, *,
     spatially tiled grid (halo'd output tiles, double-buffered input DMA)
     instead of whole-plane VMEM residency.  ``scales`` (``(R·S·C, 1)`` f32)
     marks an int8 quantized superpack: 1-byte weight tiles in VMEM,
-    dequantized per tap panel into the same f32 MXU chain."""
+    dequantized per tap panel into the same f32 MXU chain.  ``interpret``
+    defaults to the Pallas interpreter on a CPU backend only; pass
+    ``False`` to hand the kernel to Mosaic regardless of the backend."""
     b, hp, wp, c = x.shape
     r, s = taps_hw
     n = superpack.shape[1]
@@ -284,6 +309,7 @@ def untangled_conv2d_superpack_pallas(x: jax.Array, superpack: jax.Array, *,
                                lambda b_, n_, c_: (b_, 0, 0, n_)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((oh * ow, n_tile), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[..., :n]
@@ -309,7 +335,8 @@ def _conv_superpack_tiled(x, superpack, *, taps_hw, strides, rhs_dilation,
     hp_need = (n_oi - 1) * toh * sh + tin_h
     wp_need = (n_oj - 1) * tow * sw + tin_w
     k3 = superpack.reshape(r * s, c, n)
-    c_tile = min(c_tile, c)
+    # C_t is not clipped to C: the DMA'd halo slice needs a lane-dense
+    # channel dim, so narrow planes are zero-padded up to one C tile
     n_tile = min(n_tile, n)
     cp = -(-c // c_tile) * c_tile
     np_ = -(-n // n_tile) * n_tile
@@ -325,7 +352,7 @@ def _conv_superpack_tiled(x, superpack, *, taps_hw, strides, rhs_dilation,
 
     grid = (b, n_oi, n_oj, np_ // n_tile, n_c_tiles)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((r * s, c_tile, n_tile),
                      lambda b_, i_, j_, n_, c_: (0, c_, n_)),
     ]
@@ -347,6 +374,7 @@ def _conv_superpack_tiled(x, superpack, *, taps_hw, strides, rhs_dilation,
         scratch_shapes=[pltpu.VMEM((2, tin_h, tin_w, c_tile), x.dtype),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.VMEM((toh * tow, n_tile), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[:, :oh, :ow, :n]
@@ -355,7 +383,7 @@ def _conv_superpack_tiled(x, superpack, *, taps_hw, strides, rhs_dilation,
 def untangled_conv2d_pallas(x: jax.Array, kernel: jax.Array, *,
                             strides: Pair = (1, 1),
                             rhs_dilation: Pair = (1, 1),
-                            c_tile: int = 128, n_tile: int = 128,
+                            c_tile: int = LANES, n_tile: int = LANES,
                             out_dtype=None,
                             interpret: bool | None = None) -> jax.Array:
     """Valid (pre-padded) untangled convolution. x:(B,Hp,Wp,C), K:(R,S,C,N).
@@ -389,19 +417,14 @@ def _deconv_kernel(x_ref, k_ref, *rest, phases, strides: Pair,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0]                       # (Hg, Wg, C_t) resident in VMEM
     for (qh, qw, tap_off, th, tw, xh, xw, u, v, acc_off) in phases:
         if th * tw == 0 or u * v == 0:
             continue
-        acc = acc_ref[pl.ds(acc_off, u * v), :]
         for t in range(th * tw):       # static tap unroll -> MXU matmuls
             ti, tj = divmod(t, tw)
-            xs = jax.lax.slice(x, (xh + ti, xw + tj, 0),
-                               (xh + ti + u, xw + tj + v, x.shape[2]))
-            acc += jnp.dot(xs.reshape(u * v, xs.shape[2]),
-                           _tap_panel(k_ref, s_ref, tap_off + t),
-                           preferred_element_type=jnp.float32)
-        acc_ref[pl.ds(acc_off, u * v), :] = acc
+            _tap_dot(acc_ref, pl.ds(acc_off, u * v),
+                     x_ref[0, pl.ds(xh + ti, u), pl.ds(xw + tj, v), :],
+                     k_ref, s_ref, tap_off + t)
 
     @pl.when(ci == n_c_tiles - 1)
     def _flush():
@@ -409,7 +432,7 @@ def _deconv_kernel(x_ref, k_ref, *rest, phases, strides: Pair,
             if u * v == 0:
                 continue
             blk = acc_ref[pl.ds(acc_off, u * v), :]
-            o_ref[0, pl.Slice(qh, u, sh), pl.Slice(qw, v, sw), :] = (
+            o_ref[0, pl.ds(qh, u, sh), pl.ds(qw, v, sw), :] = (
                 blk.reshape(u, v, blk.shape[-1]).astype(o_ref.dtype))
 
 
@@ -417,8 +440,8 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
                               phases, out_hw: Pair, strides: Pair,
                               sum_uv: int,
                               scales: jax.Array | None = None,
-                              c_tile: int = 128,
-                              n_tile: int = 128,
+                              c_tile: int = LANES,
+                              n_tile: int = LANES,
                               sp_tiles: Pair | None = None, out_dtype=None,
                               interpret: bool | None = None) -> jax.Array:
     """Fused transposed conv: ONE kernel launch for all s_h*s_w phases.
@@ -481,6 +504,7 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
                                lambda b_, n_, c_: (b_, 0, 0, n_)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((sum_uv, n_tile), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[..., :n]
@@ -519,27 +543,21 @@ def _deconv_tiled_kernel(x_any, k_ref, *rest, phases,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = _halo_stream(x_any, buf, sem,
-                     lambda i_, j_: (i_ * tu + mh, j_ * tv + mw))
+    slot = _halo_stream(x_any, buf, sem,
+                        lambda i_, j_: (i_ * tu + mh, j_ * tv + mw))
     for pi, (qh, qw, tap_off, th, tw, xh, xw) in enumerate(phases):
-        if th * tw == 0:
-            continue                    # empty phase: its acc stays zero
-        acc = acc_ref[pl.ds(pi * tu * tv, tu * tv), :]
         for t in range(th * tw):        # static tap unroll -> MXU matmuls
-            ti, tj = divmod(t, tw)
-            xs = jax.lax.slice(x, (xh - mh + ti, xw - mw + tj, 0),
-                               (xh - mh + ti + tu, xw - mw + tj + tv,
-                                x.shape[2]))
-            acc += jnp.dot(xs.reshape(tu * tv, xs.shape[2]),
-                           _tap_panel(k_ref, s_ref, tap_off + t),
-                           preferred_element_type=jnp.float32)
-        acc_ref[pl.ds(pi * tu * tv, tu * tv), :] = acc
+            ti, tj = divmod(t, tw)      # (an empty phase's acc stays zero)
+            _tap_dot(acc_ref, pl.ds(pi * tu * tv, tu * tv),
+                     buf[slot, pl.ds(xh - mh + ti, tu),
+                         pl.ds(xw - mw + tj, tv), :],
+                     k_ref, s_ref, tap_off + t)
 
     @pl.when(ci == n_c_tiles - 1)
     def _flush():
         for pi, (qh, qw, *_rest) in enumerate(phases):
             blk = acc_ref[pl.ds(pi * tu * tv, tu * tv), :]
-            o_ref[0, pl.Slice(qh, tu, sh), pl.Slice(qw, tv, sw), :] = (
+            o_ref[0, pl.ds(qh, tu, sh), pl.ds(qw, tv, sw), :] = (
                 blk.reshape(tu, tv, blk.shape[-1]).astype(o_ref.dtype))
 
 
@@ -565,7 +583,8 @@ def _deconv_tiled(xg, superpack, *, phases, out_hw, strides, scales, c_tile,
     hg_need = mh + (n_oi - 1) * tu + tin_h
     wg_need = mw + (n_oj - 1) * tv + tin_w
     k3 = superpack.reshape(total_taps, c, n)
-    c_tile = min(c_tile, c)
+    # C_t is not clipped to C: the DMA'd halo slice needs a lane-dense
+    # channel dim, so narrow planes are zero-padded up to one C tile
     n_tile = min(n_tile, n)
     cp = -(-c // c_tile) * c_tile
     np_ = -(-n // n_tile) * n_tile
@@ -583,7 +602,7 @@ def _deconv_tiled(xg, superpack, *, phases, out_hw, strides, scales, c_tile,
                   ex.xoff[0], ex.xoff[1]) for ex in phases)
     grid = (b, n_oi, n_oj, np_ // n_tile, n_c_tiles)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((total_taps, c_tile, n_tile),
                      lambda b_, i_, j_, n_, c_: (0, c_, n_)),
     ]
@@ -606,85 +625,87 @@ def _deconv_tiled(xg, superpack, *, phases, out_hw, strides, scales, c_tile,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.VMEM((len(phases) * tu * tv, n_tile),
                                    jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*operands)
     return out[:, :oh, :ow, :n]
 
 
-def _weight_tile_bytes(total_taps, c_tile, n_tile, itemsize, witemsize):
-    """Superpack-tile VMEM bytes.  ``witemsize`` is the *weight* element
-    width when it differs from the activation ``itemsize`` (int8 superpacks:
-    1 byte/elem) — the quantized tile also carries its per-tap-row f32 scale
-    column (``ΣT · C_t`` values, 4 bytes each).  ``witemsize=None`` means
-    weights ride at the activation width (the dense f32 layout)."""
-    if witemsize is None:
-        witemsize = itemsize
-    bytes_ = witemsize * total_taps * c_tile * n_tile
-    if witemsize != itemsize:
-        bytes_ += 4 * total_taps * c_tile        # scale rows (always f32)
-    return bytes_
+# ---------------------------------------------------------------------------
+# VMEM working sets, in the layout Mosaic allocates
+# ---------------------------------------------------------------------------
+
+def _slab_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of one ``(rows, cols)`` slab: the last two dims of every
+    VMEM buffer pad to the ``(sublane, lane)`` tile — ``(8, 128)`` for
+    32-bit elements, 16 / 32 sublanes for 2- / 1-byte ones."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-cols // LANES) * LANES) * itemsize
 
 
-def vmem_bytes_estimate(hp, wp, c_tile, r, s, n_tile, oh, ow, itemsize=4,
-                        witemsize=None):
-    """Working-set estimate used by the dispatcher to pick tile sizes.
+def _working_set(in_hw: Pair, c_tile: int, total_taps: int, n_tile: int,
+                 out_hw: Pair, acc_rows: int, tap_rows: int, itemsize: int,
+                 witemsize: int | None) -> int:
+    """Peak VMEM of any of the four kernels:
 
-    Thin (r, s) wrapper over ``vmem_bytes_estimate_superpack`` — one owner
-    for the formula.  The accumulator scratch is always f32 (4 bytes/elem)
-    regardless of the input dtype; only the plane, kernel, and output blocks
-    scale with ``itemsize`` (the kernel block with ``witemsize`` when
-    quantized weights make them differ).
-    """
-    return vmem_bytes_estimate_superpack(hp, wp, c_tile, r * s, n_tile,
-                                         oh, ow, itemsize, witemsize)
+    - the input block (whole plane, or the halo tile) ``in_hw × C_t``,
+      twice: Pallas double-buffers blocked inputs, and the tiled kernels'
+      DMA scratch has two slots;
+    - the superpack tile ``(ΣT, C_t, N_t)`` at the weight itemsize, twice,
+      plus — int8 — its ``(ΣT, C_t, 1)`` f32 scale column, twice;
+    - the output block ``out_hw × N_t``, twice;
+    - the f32 accumulator ``(acc_rows, N_t)``, once;
+    - the largest tap GEMM's live values: its ``(tap_rows, C_t)`` operand
+      and ``(tap_rows, N_t)`` f32 product.
 
-
-def vmem_bytes_estimate_fused(hg, wg, c_tile, total_taps, n_tile, sum_uv,
-                              oh, ow, itemsize=4, witemsize=None):
-    """Working set of the fused multi-phase kernel: global plane block +
-    superpack tile (1-byte elements + f32 scale rows when quantized) + full
-    interleaved output block, plus the per-phase f32 accumulator scratch
-    (always 4 bytes/elem)."""
-    return itemsize * (hg * wg * c_tile + oh * ow * n_tile) \
-        + _weight_tile_bytes(total_taps, c_tile, n_tile, itemsize,
-                             witemsize) \
-        + 4 * sum_uv * n_tile
+    ``witemsize`` is the weight element width when it differs from the
+    activation ``itemsize`` (int8 superpacks: 1); ``None`` means weights
+    ride at the activation width."""
+    wit = itemsize if witemsize is None else witemsize
+    weights = total_taps * _slab_bytes(c_tile, n_tile, wit)
+    if wit != itemsize:
+        weights += total_taps * _slab_bytes(c_tile, 1, 4)
+    return (2 * in_hw[0] * _slab_bytes(in_hw[1], c_tile, itemsize)
+            + 2 * weights
+            + 2 * out_hw[0] * _slab_bytes(out_hw[1], n_tile, itemsize)
+            + _slab_bytes(acc_rows, n_tile, 4)
+            + _slab_bytes(tap_rows, c_tile, itemsize)
+            + _slab_bytes(tap_rows, n_tile, 4))
 
 
 def vmem_bytes_estimate_superpack(hp, wp, c_tile, total_taps, n_tile,
                                   oh, ow, itemsize=4, witemsize=None):
-    """Working set of the single-correlation superpack kernel — the
+    """Working set of the whole-plane single-correlation kernel — the
     dilation-aware estimate: ``hp``/``wp`` are padded-plane dims that grow
     with the dilated tap reach ``(R-1)·d``, while the superpack tile stays
     ``total_taps = R·S`` rows no matter the dilation (no zero-inserted
-    kernel is ever resident).  The superpack tile shrinks to 1 byte/elem
-    (+ f32 scale rows) for int8 weights.  f32 accumulator always at
-    4 bytes/elem."""
-    return itemsize * (hp * wp * c_tile + oh * ow * n_tile) \
-        + _weight_tile_bytes(total_taps, c_tile, n_tile, itemsize,
-                             witemsize) \
-        + 4 * oh * ow * n_tile
+    kernel is ever resident)."""
+    return _working_set((hp, wp), c_tile, total_taps, n_tile, (oh, ow),
+                        oh * ow, oh * ow, itemsize, witemsize)
+
+
+def vmem_bytes_estimate_fused(hg, wg, c_tile, total_taps, n_tile, sum_uv,
+                              oh, ow, tap_rows, itemsize=4, witemsize=None):
+    """Working set of the fused multi-phase kernel: global plane block +
+    superpack tile + full interleaved output block, the ``sum_uv``-row
+    per-phase f32 accumulator, and the largest phase's ``tap_rows = U·V``
+    tap GEMM."""
+    return _working_set((hg, wg), c_tile, total_taps, n_tile, (oh, ow),
+                        sum_uv, tap_rows, itemsize, witemsize)
 
 
 def vmem_bytes_estimate_tiled(tin_h, tin_w, c_tile, total_taps, n_tile,
-                              acc_rows, itemsize=4, witemsize=None):
-    """Working set of the spatially tiled kernels (both kinds):
-
-    - ``2 · tin_h · tin_w · C_t`` — the halo'd input tile, **twice** (the
-      double buffer: one slot computing, one streaming the next halo
-      slice), at the input itemsize;
-    - ``total_taps · C_t · N_t`` — the superpack tile (R·S taps for the
-      single-correlation kind, ΣT for the multi-phase deconv), at the
-      weight itemsize (1 byte + f32 scale rows when quantized);
-    - ``acc_rows · N_t`` — the output block at the input itemsize *plus*
-      the f32 accumulator at a fixed 4 bytes/elem.  ``acc_rows`` is the
-      output-tile pixel count: ``T_oh·T_ow`` (single) or ``s_h·s_w·T_u·T_v``
-      (deconv — every phase's segment of the shared scratch).
+                              out_tile, acc_rows, tap_rows, itemsize=4,
+                              witemsize=None):
+    """Working set of the spatially tiled kernels (both kinds): the halo'd
+    input tile ``(tin_h, tin_w)`` in its two DMA slots, the superpack tile,
+    the output tile ``out_tile`` (``(T_oh, T_ow)``; the deconv's interleaved
+    ``(T_u·s_h, T_v·s_w)``), the f32 accumulator (``acc_rows``: ``T_oh·T_ow``
+    single, ``s_h·s_w·T_u·T_v`` deconv) and one tap GEMM of ``tap_rows``
+    (``T_oh·T_ow`` / ``T_u·T_v``).
 
     ``tin_* = halo_extent(tile, taps, stride, dilation)`` for the single
     kind; the deconv's halo is the phase tap-origin span plus the tile
     (``deconv_tap_span``)."""
-    return itemsize * (2 * tin_h * tin_w * c_tile + acc_rows * n_tile) \
-        + _weight_tile_bytes(total_taps, c_tile, n_tile, itemsize,
-                             witemsize) \
-        + 4 * acc_rows * n_tile
+    return _working_set((tin_h, tin_w), c_tile, total_taps, n_tile,
+                        out_tile, acc_rows, tap_rows, itemsize, witemsize)

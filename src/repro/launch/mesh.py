@@ -10,16 +10,10 @@ from __future__ import annotations
 
 import jax
 
-# jax 0.4.x has no jax.sharding.AxisType (meshes are Auto by default);
-# pass axis_types only on versions that support it
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _mesh(shape, axes):
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -5,6 +5,7 @@ PartitionSpecs (resolved to mesh axes by ``repro.sharding``).
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Any, Callable, Sequence
 
 import jax
@@ -31,18 +32,18 @@ def dense_init(key, in_dim, out_dim, in_axis, out_axis, dtype=jnp.bfloat16,
     return params, specs
 
 
-# XLA:CPU's thunk runtime lacks some fused BF16xBF16->F32 dot kernels; upcast
-# on CPU only (trace-time constant — no effect on the TPU target).  The
-# dry-run (compile-only, REPRO_DRYRUN=1) keeps bf16 so cost_analysis reports
-# the TPU-faithful byte counts.
-import os as _os
-
-_CPU_BACKEND = (jax.default_backend() == "cpu"
-                and _os.environ.get("REPRO_DRYRUN") != "1")
+def _upcast_bf16() -> bool:
+    """XLA:CPU's thunk runtime lacks some fused BF16xBF16->F32 dot kernels,
+    so bf16 dots upcast on the CPU backend only.  The dry-run (compile-only,
+    ``REPRO_DRYRUN=1``) keeps bf16 so cost_analysis reports the
+    TPU-faithful byte counts.  Asked at trace time: importing this module
+    initialises no backend."""
+    return (jax.default_backend() == "cpu"
+            and os.environ.get("REPRO_DRYRUN") != "1")
 
 
 def _dot_operands(x, w):
-    if _CPU_BACKEND and x.dtype == jnp.bfloat16:
+    if x.dtype == jnp.bfloat16 and _upcast_bf16():
         return x.astype(jnp.float32), w.astype(jnp.float32)
     return x, w
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.layers import common as cm
-from repro.sharding import shard_map_compat
 
 
 def moe_init(key, cfg, dtype=jnp.bfloat16):
@@ -119,11 +118,11 @@ def moe_apply_ep(p, x, cfg, dist):
     ba, ma = dist.batch_axes, dist.model_axis
     n_model = mesh.shape[ma]
     body = partial(_ep_local_body, cfg=cfg, model_axis=ma, n_model=n_model)
-    f = shard_map_compat(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(ba, None), P(None, None), P(None),
                   P(ma), P(ma), P(ma)),
-        out_specs=P(ba, None))
+        out_specs=P(ba, None), check_vma=False)
     y = f(x.reshape(b * s, d), p["router"], p["bias"], p["wi"], p["wg"],
           p["wo"])
     return y.reshape(b, s, d)
@@ -198,11 +197,11 @@ def moe_apply_ep_a2a(p, x, cfg, dist):
         x2 = jnp.pad(x2, ((0, padded - tokens), (0, 0)))
         valid = jnp.pad(valid, (0, padded - tokens))
     body = partial(_ep_a2a_body, cfg=cfg, axes=ep_axes)
-    f = shard_map_compat(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(tok_axes, None), P(tok_axes), P(None, None), P(None),
                   P(ep_axes), P(ep_axes), P(ep_axes)),
-        out_specs=P(tok_axes, None))
+        out_specs=P(tok_axes, None), check_vma=False)
     y = f(x2, valid, p["router"], p["bias"], p["wi"], p["wg"], p["wo"])
     return y[:tokens].reshape(b, s, d)
 

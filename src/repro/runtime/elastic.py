@@ -16,10 +16,6 @@ import jax
 from repro.sharding import DistContext
 from repro.train.checkpoint import CheckpointManager
 
-# jax.sharding.AxisType landed after the pinned jax; Auto is the default
-# axis type either way, so pass it only where available
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def shrink_mesh(devices_left: int, model: int, pod: int = 0):
     """Largest (data, model) mesh from the surviving chips, TP preserved."""
@@ -30,9 +26,8 @@ def shrink_mesh(devices_left: int, model: int, pod: int = 0):
         data *= 2
     shape = (pod, data, model) if pod else (data, model)
     names = ("pod", "data", "model") if pod else ("data", "model")
-    kw = ({"axis_types": (_AXIS_TYPE.Auto,) * len(shape)}
-          if _AXIS_TYPE is not None else {})
-    return jax.make_mesh(shape, names, **kw)
+    return jax.make_mesh(
+        shape, names, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def restore_on_mesh(ckpt: CheckpointManager, template, logical_specs,

@@ -46,21 +46,6 @@ SUPERPACK_SPEC = P("conv_taps", "conv_out")
 PLANE_SPEC = P("batch", "plane_h", "plane_w")
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """``shard_map`` across the jax versions this repo supports: new
-    releases expose ``jax.shard_map`` with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  The
-    replication check defaults off — the plane-parallel bodies return
-    device-varying tiles and psum weight cotangents through the
-    ``shard_map`` transpose, which the 0.4.x checker cannot type."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
-
-
 # (param-path, axis) pairs already warned about by ``shard_params`` — the
 # best-effort replication fallback is silent-by-design per call site, but
 # the *first* hit for a given param deserves a visible trace.

@@ -155,6 +155,15 @@ def assert_close_ulp(got, y64, amax64, n_terms, out_dtype=jnp.float32):
         f"max_bound={bound.max():.3e})")
 
 
+def vmem_slab(rows, cols, itemsize):
+    """Bytes of one ``(rows, cols)`` VMEM slab in Mosaic's layout: rows pad
+    to the sublane tile (8 for 4-byte elements, 16 / 32 for 2 / 1), cols to
+    the 128-lane tile — the reference the VMEM-estimate tests spell their
+    expected working sets in."""
+    sub = 8 * 4 // itemsize
+    return -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+
+
 # ---------------------------------------------------------------------------
 # superpack round-trip builders
 # ---------------------------------------------------------------------------

@@ -21,10 +21,9 @@ def _mesh_capability() -> str | None:
         "import jax\n"
         "from jax.sharding import PartitionSpec as P\n"
         "from repro.launch.mesh import make_host_mesh\n"
-        "from repro.sharding import shard_map_compat\n"
         "m = make_host_mesh(data=2, model=2)\n"
-        "f = shard_map_compat(lambda x: x * 2, m, in_specs=P('data'),\n"
-        "                     out_specs=P('data'))\n"
+        "f = jax.shard_map(lambda x: x * 2, mesh=m, in_specs=P('data'),\n"
+        "                  out_specs=P('data'))\n"
         "f(jax.numpy.ones((4,)))\n"
         "print(len(list(m.devices.flat)))\n")
     try:
@@ -172,8 +171,6 @@ def test_crosspod_compressed_allreduce():
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
     from repro.runtime import compress
-    from repro.sharding import shard_map_compat
-
     mesh = make_host_mesh(data=2, model=1, pod=2)
     grads = {'w': jnp.stack([jnp.full((4,), float(i)) for i in range(2)])}
     errs = {'w': jnp.zeros((2, 4))}
@@ -181,9 +178,10 @@ def test_crosspod_compressed_allreduce():
     def f(g, e):
         return compress.crosspod_allreduce_compressed(g, e, 'pod')
 
-    fm = shard_map_compat(f, mesh,
-                          in_specs=({'w': P('pod', None)},) * 2,
-                          out_specs=({'w': P('pod', None)},) * 2)
+    fm = jax.shard_map(f, mesh=mesh,
+                       in_specs=({'w': P('pod', None)},) * 2,
+                       out_specs=({'w': P('pod', None)},) * 2,
+                       check_vma=False)
     with mesh:
         mean, new_e = fm(grads, errs)
     # mean over pods of [0, 1] = 0.5 everywhere

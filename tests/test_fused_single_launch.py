@@ -12,7 +12,7 @@ from repro.core import reference as ref
 from repro.core.plan import ConvSpec, conv_spec, plan_conv
 from repro.models.gan import DCGAN_LAYERS
 
-from tests.conftest import assert_close, count_eqns
+from tests.conftest import assert_close, count_eqns, vmem_slab
 
 
 # ---------------------------------------------------------------------------
@@ -178,24 +178,33 @@ def test_fused_pallas_bf16_and_ragged_tiles():
 # ---------------------------------------------------------------------------
 
 def test_vmem_estimate_accumulator_is_f32():
-    from repro.kernels.untangled_conv import (vmem_bytes_estimate,
-                                              vmem_bytes_estimate_fused)
+    from repro.kernels.untangled_conv import (vmem_bytes_estimate_fused,
+                                              vmem_bytes_estimate_superpack)
     hp = wp = 16; c_t = n_t = 8; r = s = 3; oh = ow = 14
     for itemsize in (1, 2, 4):
-        est = vmem_bytes_estimate(hp, wp, c_t, r, s, n_t, oh, ow, itemsize)
-        streamed = itemsize * (hp * wp * c_t + r * s * c_t * n_t
-                               + oh * ow * n_t)
-        # the accumulator contribution is itemsize-independent: always f32
-        assert est - streamed == 4 * oh * ow * n_t
-    est2 = vmem_bytes_estimate_fused(hp, wp, c_t, r * s, n_t, oh * ow,
-                                     oh, ow, itemsize=2)
-    streamed2 = 2 * (hp * wp * c_t + r * s * c_t * n_t + oh * ow * n_t)
-    assert est2 - streamed2 == 4 * oh * ow * n_t
+        est = vmem_bytes_estimate_superpack(hp, wp, c_t, r * s, n_t, oh, ow,
+                                            itemsize)
+        streamed = (2 * hp * vmem_slab(wp, c_t, itemsize)
+                    + 2 * r * s * vmem_slab(c_t, n_t, itemsize)
+                    + 2 * oh * vmem_slab(ow, n_t, itemsize)
+                    + vmem_slab(oh * ow, c_t, itemsize))
+        # accumulator + tap product are itemsize-independent: always f32
+        assert est - streamed == 2 * vmem_slab(oh * ow, n_t, 4)
+    # the fused kernel: a sum_uv-row accumulator, a tap_rows-row product
+    est2 = vmem_bytes_estimate_fused(hp, wp, c_t, r * s, n_t, 4 * oh * ow,
+                                     oh, ow, oh * ow, itemsize=2)
+    streamed2 = (2 * hp * vmem_slab(wp, c_t, 2)
+                 + 2 * r * s * vmem_slab(c_t, n_t, 2)
+                 + 2 * oh * vmem_slab(ow, n_t, 2)
+                 + vmem_slab(oh * ow, c_t, 2))
+    assert est2 - streamed2 == (vmem_slab(4 * oh * ow, n_t, 4)
+                                + vmem_slab(oh * ow, n_t, 4))
 
 
 def test_bf16_plan_picks_tiles_accounting_f32_scratch():
     """A bf16 spec must not get bigger tiles than the f32 scratch allows:
     the estimate at itemsize=2 still carries the 4-byte accumulator."""
+    import repro.core.plan as planmod
     from repro.kernels.untangled_conv import vmem_bytes_estimate_fused
     plan = plan_conv(ConvSpec(
         kind="transposed", in_hw=(16, 16), in_c=256, out_c=256,
@@ -206,6 +215,8 @@ def test_bf16_plan_picks_tiles_accounting_f32_scratch():
     c_t, n_t = plan.tiles
     (glh, ghh), (glw, ghw) = plan.gpad
     hg, wg = 16 + glh + ghh, 16 + glw + ghw
+    tap_rows = max(ex.out_hw[0] * ex.out_hw[1] for ex in plan.phases)
     est = vmem_bytes_estimate_fused(hg, wg, c_t, plan.total_taps, n_t,
-                                    plan.sum_uv, *plan.out_hw, itemsize=2)
-    assert est <= 12 * 1024 * 1024
+                                    plan.sum_uv, *plan.out_hw, tap_rows,
+                                    itemsize=2)
+    assert est <= planmod._VMEM_BUDGET

@@ -59,3 +59,25 @@ def test_fixture_records_the_reclaimed_geometry():
     assert all(r["path"] == "taps" for r in xla["routes"])
     assert all(r["path"] == "pallas" and r["sp_tiles"]
                for r in pallas["routes"])
+
+
+def test_every_pallas_verdict_has_lane_aligned_tiles():
+    """Mosaic lays a channel tile out only when it is a multiple of the
+    128-lane tile or the whole dim; its strided loads and stores take at
+    most one lane tile, and the tiled kernels' halo DMA needs a lane-dense
+    channel dim.  So every 'pallas' row of the fixture carries
+    ``C_t, N_t ∈ {128} ∪ {the dim itself, when narrower}`` — with
+    ``C_t == 128`` on the spatially tiled kernels."""
+    table = json.loads(pathlib.Path(FIXTURE).read_text())
+    n_checked = 0
+    for e in table["entries"]:
+        c, n = e["spec"]["in_c"], e["spec"]["out_c"]
+        for r in e["routes"]:
+            if r["path"] != "pallas":
+                continue
+            c_t, n_t = r["tiles"]
+            assert n_t == min(n, 128), (e["name"], r)
+            want_c = 128 if r["sp_tiles"] else min(c, 128)
+            assert c_t == want_c, (e["name"], r)
+            n_checked += 1
+    assert n_checked > 0

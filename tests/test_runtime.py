@@ -1,6 +1,10 @@
-"""Checkpoint manager, data pipeline, grad compression.  (The fault
-runtime's unit coverage moved to tests/test_fault.py.)"""
+"""Checkpoint manager, data pipeline, grad compression, the compile
+cache, import hygiene.  (The fault runtime's unit coverage moved to
+tests/test_fault.py.)"""
 import os
+import pathlib
+import subprocess
+import sys
 import time
 
 import jax
@@ -122,3 +126,45 @@ def test_error_feedback_reduces_bias():
     mean_nofb = np.mean(np.stack(acc_nofb), axis=0)
     assert (np.abs(mean_fb - np.asarray(g_true)).mean()
             <= np.abs(mean_nofb - np.asarray(g_true)).mean() + 1e-9)
+
+
+# ---------------------------------------------------------------------------
+# compile cache + import hygiene
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_dir(monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set; else
+    the fixed ``<checkout>/.jax_cache`` (git-ignored)."""
+    from repro.runtime import compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = pathlib.Path(compile_cache.enable_compile_cache())
+        assert jax.config.jax_compilation_cache_dir == str(got)
+        root = pathlib.Path(__file__).resolve().parent.parent
+        assert got == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_importing_the_package_initialises_no_backend():
+    """A parent that touched a backend would hold the chip: importing any
+    module of the package (models, serving, kernels, ...) must not."""
+    code = (
+        "import importlib, pkgutil\n"
+        "from jax._src import xla_bridge\n"
+        "import repro\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    importlib.import_module(m.name)\n"
+        "    assert not xla_bridge._backends, m.name\n"
+        "print('imported, no backend')\n")
+    src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "imported, no backend" in out.stdout

@@ -10,10 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.core.plan as planmod
 from repro.core import reference as ref
 from repro.core.plan import ConvSpec, conv_spec, plan_conv
 
-from tests.conftest import assert_close, count_eqns, plane_bytes_cap
+from tests.conftest import (assert_close, count_eqns, plane_bytes_cap,
+                            vmem_slab)
 
 
 # ---------------------------------------------------------------------------
@@ -230,17 +232,22 @@ def test_negative_padding_vjp(single_plan):
 
 def test_vmem_estimate_superpack_is_dilation_aware():
     from repro.kernels.untangled_conv import vmem_bytes_estimate_superpack
-    # same tap count, larger plane: dilation grows the plane term only
+    # same tap count, larger plane: dilation grows the plane term only —
+    # the double-buffered plane block, each row padded to (8, 128) tiles
     small = vmem_bytes_estimate_superpack(18, 18, 8, 9, 8, 16, 16)
     big = vmem_bytes_estimate_superpack(32, 32, 8, 9, 8, 16, 16)
     assert big > small
-    assert big - small == 4 * (32 * 32 - 18 * 18) * 8
-    # f32 accumulator is itemsize-independent
+    assert big - small == 2 * (32 * vmem_slab(32, 8, 4)
+                               - 18 * vmem_slab(18, 8, 4))
+    # f32 accumulator and tap product are itemsize-independent
     for itemsize in (1, 2, 4):
         est = vmem_bytes_estimate_superpack(18, 18, 8, 9, 8, 16, 16,
                                             itemsize)
-        streamed = itemsize * (18 * 18 * 8 + 9 * 8 * 8 + 16 * 16 * 8)
-        assert est - streamed == 4 * 16 * 16 * 8
+        streamed = (2 * 18 * vmem_slab(18, 8, itemsize)
+                    + 2 * 9 * vmem_slab(8, 8, itemsize)
+                    + 2 * 16 * vmem_slab(16, 8, itemsize)
+                    + vmem_slab(16 * 16, 8, itemsize))
+        assert est - streamed == 2 * vmem_slab(16 * 16, 8, 4)
 
 
 def test_pallas_plan_tiles_respect_budget():
@@ -253,4 +260,4 @@ def test_pallas_plan_tiles_respect_budget():
     from repro.kernels.untangled_conv import vmem_bytes_estimate_superpack
     c_t, n_t = plan.tiles
     est = vmem_bytes_estimate_superpack(41, 41, c_t, 9, n_t, *plan.out_hw)
-    assert est <= 12 * 1024 * 1024
+    assert est <= planmod._VMEM_BUDGET

@@ -148,10 +148,9 @@ def _capability() -> str | None:
         "import jax\n"
         "from jax.sharding import PartitionSpec as P\n"
         "from repro.launch.mesh import make_spatial_mesh\n"
-        "from repro.sharding import shard_map_compat\n"
         "m = make_spatial_mesh(2, 2)\n"
-        "f = shard_map_compat(lambda x: x * 2, m, in_specs=P('sp_h'),\n"
-        "                     out_specs=P('sp_h'))\n"
+        "f = jax.shard_map(lambda x: x * 2, mesh=m, in_specs=P('sp_h'),\n"
+        "                  out_specs=P('sp_h'))\n"
         "f(jax.numpy.ones((4,)))\n"
         "print(jax.device_count())\n")
     try:
