@@ -310,6 +310,7 @@ def untangled_conv2d_superpack_pallas(x: jax.Array, superpack: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((oh * ow, n_tile), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="untangled_conv",
         interpret=interpret,
     )(*operands)
     return out[..., :n]
@@ -375,6 +376,7 @@ def _conv_superpack_tiled(x, superpack, *, taps_hw, strides, rhs_dilation,
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.VMEM((toh * tow, n_tile), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="untangled_conv_tiled",
         interpret=interpret,
     )(*operands)
     return out[:, :oh, :ow, :n]
@@ -505,6 +507,7 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((sum_uv, n_tile), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="untangled_deconv",
         interpret=interpret,
     )(*operands)
     return out[..., :n]
@@ -626,6 +629,7 @@ def _deconv_tiled(xg, superpack, *, phases, out_hw, strides, scales, c_tile,
                         pltpu.VMEM((len(phases) * tu * tv, n_tile),
                                    jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
+        name="untangled_deconv_tiled",
         interpret=interpret,
     )(*operands)
     return out[:, :oh, :ow, :n]

@@ -165,10 +165,12 @@ def generator_init(key, cfg: GANConfig, dtype=jnp.float32, dist=None):
 def generator_apply(p, z, cfg: GANConfig):
     plans = generator_plans(cfg, z.dtype)      # cache hits after model load
     l0 = cfg.layers[0]
-    x = (z @ p["proj"]).reshape(z.shape[0], l0.in_hw, l0.in_hw, l0.in_c)
-    x = jax.nn.relu(x)
+    with jax.named_scope("proj"):
+        x = z @ p["proj"]
+    x = jax.nn.relu(x.reshape(z.shape[0], l0.in_hw, l0.in_hw, l0.in_c))
     for i, plan in enumerate(plans):
-        x = plan.apply(x, p[f"dc{i}"])
+        with jax.named_scope(f"dc{i}"):         # names the site in a profile
+            x = plan.apply(x, p[f"dc{i}"])
         x = x + p[f"b{i}"]
         x = jnp.tanh(x) if i == len(plans) - 1 else jax.nn.relu(x)
     return x
@@ -212,7 +214,8 @@ def discriminator_init(key, cfg: GANConfig, dtype=jnp.float32, dist=None):
 def discriminator_apply(p, x, cfg: GANConfig):
     plans = discriminator_plans(cfg, x.dtype)
     for i, plan in enumerate(plans):
-        x = plan.apply(x, p[f"c{i}"])       # superpack or legacy HWIO kernel
+        with jax.named_scope(f"c{i}"):
+            x = plan.apply(x, p[f"c{i}"])   # superpack or legacy HWIO kernel
         x = jax.nn.leaky_relu(x, 0.2)
     return x.reshape(x.shape[0], -1) @ p["head"]
 

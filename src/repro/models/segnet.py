@@ -155,7 +155,9 @@ def segnet_apply(p, x, cfg: SegNetConfig):
     plans = segnet_plans(cfg, x.dtype)          # cache hits after model load
     n_layers = len(plans)
     for i, plan in enumerate(plans):
-        x = plan.apply(x, p[f"w{i}"]) + p[f"b{i}"]
+        with jax.named_scope(f"w{i}"):
+            x = plan.apply(x, p[f"w{i}"])
+        x = x + p[f"b{i}"]
         if i < n_layers - 1:
             x = jax.nn.relu(x)
     return x

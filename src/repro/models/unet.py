@@ -185,7 +185,9 @@ def unet_apply(p, x, t, cfg: UNetConfig):
     plans = unet_plans(cfg, x.dtype)           # cache hits after model load
 
     def conv(name, h):
-        return plans[name].apply(h, p[name]) + p[f"{name}_b"]
+        with jax.named_scope(name):
+            h = plans[name].apply(h, p[name])
+        return h + p[f"{name}_b"]
 
     emb = jax.nn.silu(
         time_embedding(t.astype(x.dtype), cfg.time_dim)

@@ -176,7 +176,9 @@ def encode(p, x, cfg: VAEConfig):
     """x (B, H, W, C) -> (mu, logvar), each (B, latent_dim)."""
     plans = encoder_plans(cfg, x.dtype)        # cache hits after model load
     for i, plan in enumerate(plans):
-        x = jax.nn.relu(plan.apply(x, p[f"enc{i}"]) + p[f"encb{i}"])
+        with jax.named_scope(f"enc{i}"):
+            x = plan.apply(x, p[f"enc{i}"])
+        x = jax.nn.relu(x + p[f"encb{i}"])
     h = x.reshape(x.shape[0], -1)
     return h @ p["mu_w"] + p["mu_b"], h @ p["lv_w"] + p["lv_b"]
 
@@ -188,7 +190,9 @@ def decode(p, z, cfg: VAEConfig):
     h = jax.nn.relu(z @ p["proj"] + p["projb"])
     x = h.reshape(z.shape[0], cfg.feat_hw, cfg.feat_hw, cfg.feat_c)
     for i, plan in enumerate(plans):
-        x = plan.apply(x, p[f"dec{i}"]) + p[f"decb{i}"]
+        with jax.named_scope(f"dec{i}"):
+            x = plan.apply(x, p[f"dec{i}"])
+        x = x + p[f"decb{i}"]
         x = jnp.tanh(x) if i == len(plans) - 1 else jax.nn.relu(x)
     return x
 

@@ -32,7 +32,10 @@ admission → schedule → launch → replay
   costs shared via the ``RouteCache``); LM models advance one
   ``ContinuousBatcher`` decode step per pump.  Every launch wall-time
   feeds a per-(model, bucket) ``StragglerMonitor``; flagged buckets
-  surface as the slow-bucket alert in ``stats()``.
+  surface as the slow-bucket alert in ``stats()``.  Each launch stamps its
+  requests with ``launch_seq`` and ``t_launch``, so queue wait
+  (``t_launch - t_arrival``) and service (``t_done - t_launch``) are read
+  per request.
 - **Replay** (the fault ladder): a ``FailureInjector`` (or a real
   ``NodeFailure``) firing at a launch boundary kills that launch's
   results.  The control plane re-queues the affected live requests at the
@@ -56,6 +59,12 @@ within deadline / submitted — rejected, shed, and served-but-late all
 count against it), fault/replay records, and the straggler alert; the
 open-loop tail-latency harness in ``benchmarks/serve_bench.py`` turns the
 same report into ``BENCH_slo.json``.
+
+Tracing: schedule and launch are ``jax.profiler.TraceAnnotation`` spans
+(``huge2.schedule``, ``huge2.launch`` and, inside the batcher, its
+``huge2.launch.*`` steps), recorded only while a profiler traces the
+process and on the same clock as the device's operations
+(docs/ARCHITECTURE.md, "Tracing").
 """
 from __future__ import annotations
 
@@ -67,6 +76,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.plan import BATCH_BUCKETS
 from repro.runtime.fault import NodeFailure, StragglerMonitor
@@ -96,6 +106,10 @@ class ServeRequest:
     # None = stamped by the control plane's injected clock at submit
     # (deadline tests / open-loop drivers stamp explicitly, same domain)
     t_arrival: Optional[float] = None
+    # stamped at launch (image backends): the control plane's launch_seq
+    # of the launch that answered, and its start on the same clock
+    launch_seq: Optional[int] = None
+    t_launch: Optional[float] = None
     t_done: Optional[float] = None
     out: Optional[np.ndarray] = None
     status: str = "queued"
@@ -134,6 +148,9 @@ class ImageBackend:
     queue stays empty in this mode)."""
 
     kind = "image"
+    # the control plane's launch_seq of the launch in progress, set before
+    # each ``launch`` (whose signature callers wrap) to label its spans
+    launch_seq: Optional[int] = None
 
     def __init__(self, name: str, serve_fn: Callable, proto: np.ndarray, *,
                  buckets: Sequence[int] = BATCH_BUCKETS,
@@ -171,7 +188,7 @@ class ImageBackend:
 
     def launch(self, payloads: Sequence[np.ndarray],
                bucket: int) -> np.ndarray:
-        return self.batcher.execute(payloads, bucket)
+        return self.batcher.execute(payloads, bucket, seq=self.launch_seq)
 
     def rebind(self, dist, serve_fn: Optional[Callable] = None):
         self.batcher.rebind_dist(dist, serve_fn)
@@ -272,10 +289,11 @@ class ControlPlane:
         self.starvation_s = starvation_ms / 1e3
         # ONE monotonic clock for every scheduling timestamp: arrivals,
         # admission ('now + est > deadline'), shedding ('now > deadline'),
-        # max-wait expiry — and it is handed down to every image backend's
-        # batcher, so admission and the batcher's coalescing deadline can
-        # never disagree about 'now'.  Compute-cost durations (_observe
-        # timing) stay on time.perf_counter: they measure the device.
+        # max-wait expiry, launch start and end — and it is handed down to
+        # every image backend's batcher, so admission and the batcher's
+        # coalescing deadline can never disagree about 'now'.  An image
+        # launch's duration for its StragglerMonitor is taken from the same
+        # two stamps as t_launch and t_done.
         self.clock = clock
         self.injector = injector
         self.admission = admission
@@ -400,8 +418,11 @@ class ControlPlane:
                 ddl = min((h.deadline for h in heads
                            if h.deadline is not None), default=float("inf"))
                 return (ddl, min(h.t_arrival for h in heads))
-            name = min(due, key=urgency)
-            finished += self._launch_image(name, now)
+            with TraceAnnotation("huge2.schedule", seq=self.launch_seq + 1):
+                name = min(due, key=urgency)
+                reqs, size = self._schedule_image(name, now)
+            if reqs:
+                finished += self._execute(self.backends[name], reqs, size)
         return finished
 
     def _take(self, name: str, cls: str, want: int,
@@ -421,7 +442,9 @@ class ControlPlane:
                 out.append(r)
         return out
 
-    def _launch_image(self, name: str, now: float) -> list[ServeRequest]:
+    def _schedule_image(self, name: str,
+                        now: float) -> tuple[list[ServeRequest], int]:
+        """The requests of model ``name``'s next launch and its bucket."""
         be, q = self.backends[name], self.queues[name]
         cls = self._pick_class(q, now)
         n = len(q["interactive"]) + len(q["batch"])
@@ -429,9 +452,7 @@ class ControlPlane:
         reqs = self._take(name, cls, size, now)
         other = "batch" if cls == "interactive" else "interactive"
         reqs += self._take(name, other, size - len(reqs), now)  # backfill
-        if not reqs:
-            return []
-        return self._execute(be, reqs, size)
+        return reqs, size
 
     def _pump_lm(self, now: float) -> list[ServeRequest]:
         finished = []
@@ -463,19 +484,27 @@ class ControlPlane:
     def _execute(self, be: ImageBackend, reqs: list[ServeRequest],
                  bucket: int) -> list[ServeRequest]:
         self.launch_seq += 1
-        t0 = time.perf_counter()
+        seq = be.launch_seq = self.launch_seq
+        t0 = self.clock()
+        waits = [t0 - r.t_arrival for r in reqs]
+        for r in reqs:
+            r.launch_seq, r.t_launch = seq, t0
         try:
             if self.injector is not None:
-                self.injector.check(self.launch_seq)   # device lost mid-batch
-            outs = be.launch([r.payload for r in reqs], bucket)
+                self.injector.check(seq)              # device lost mid-batch
+            with TraceAnnotation(
+                    "huge2.launch", seq=seq, model=be.name, bucket=bucket,
+                    live=len(reqs), wait_us_sum=sum(waits) * 1e6,
+                    wait_us_max=max(waits) * 1e6):
+                outs = be.launch([r.payload for r in reqs], bucket)
         except NodeFailure as e:
             self._on_failure(be, reqs, e)
             return []
-        self._observe(be.name, bucket, time.perf_counter() - t0)
-        now = self.clock()
+        t1 = self.clock()
+        self._observe(be.name, bucket, t1 - t0)
         for r, out in zip(reqs, outs):
             r.out = out
-            r.t_done = now
+            r.t_done = t1
             self._commit(r)
         return reqs
 
@@ -499,8 +528,7 @@ class ControlPlane:
                         reverse=True):
             r.replays += 1
             r.status = "queued"
-            r.out = None
-            r.t_done = None
+            r.out = r.t_done = r.launch_seq = r.t_launch = None
             self.queues[be.name][r.priority].appendleft(r)
         if self.on_fault is not None:
             self.on_fault(self, err)

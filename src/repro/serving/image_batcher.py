@@ -43,7 +43,10 @@ Under the SLO-aware control plane (``serving/control_plane.py``) this
 batcher is no longer a peer entry point but the *launch engine* of an
 ``ImageBackend``: the control plane owns admission/priorities/deadlines
 and calls ``execute`` directly; ``rebind_dist`` is its elastic-degrade
-hook after replica loss.
+hook after replica loss.  ``execute`` brackets each step of a launch in a
+``jax.profiler.TraceAnnotation`` (``huge2.launch.stack``, ``.h2d``,
+``.dispatch``, ``.wait``, ``.d2h``) carrying the control plane's launch
+number, recorded only while a profiler traces the process.
 """
 from __future__ import annotations
 
@@ -54,6 +57,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.plan import BATCH_BUCKETS
 from repro.serving.metrics import latency_stats
@@ -258,22 +262,31 @@ class DynamicImageBatcher:
         return self.done
 
     def execute(self, rows: Sequence[np.ndarray],
-                bucket: Optional[int] = None) -> np.ndarray:
+                bucket: Optional[int] = None,
+                seq: Optional[int] = None) -> np.ndarray:
         """Pad ``rows`` up to ``bucket`` and run ONE jitted launch,
         returning the live output rows with no request bookkeeping — the
         control plane's entry point (``serving.control_plane`` owns its
         own queues and uses this batcher purely as the launch engine).
         The launch is still recorded in ``launches`` so pad-fraction
-        stats cover both callers."""
+        stats cover both callers; ``seq`` (the control plane's
+        ``launch_seq``) labels the launch's trace spans."""
         bucket = self.bucket_for(len(rows)) if bucket is None else bucket
-        batch = np.stack([np.asarray(r) for r in rows])
-        if len(rows) < bucket:                       # pad the tail
-            pad = np.zeros((bucket - len(rows),) + batch.shape[1:],
-                           batch.dtype)
-            batch = np.concatenate([batch, pad])
-        out = jax.block_until_ready(self._serve(jax.numpy.asarray(batch)))
+        with TraceAnnotation("huge2.launch.stack", seq=seq):
+            batch = np.stack([np.asarray(r) for r in rows])
+            if len(rows) < bucket:                   # pad the tail
+                pad = np.zeros((bucket - len(rows),) + batch.shape[1:],
+                               batch.dtype)
+                batch = np.concatenate([batch, pad])
+        with TraceAnnotation("huge2.launch.h2d", seq=seq):
+            x = jax.device_put(batch)
+        with TraceAnnotation("huge2.launch.dispatch", seq=seq):
+            out = self._serve(x)
+        with TraceAnnotation("huge2.launch.wait", seq=seq):
+            out = jax.block_until_ready(out)
         self.launches.append((bucket, len(rows)))
-        return np.asarray(out)[:len(rows)]
+        with TraceAnnotation("huge2.launch.d2h", seq=seq):
+            return np.asarray(out)[:len(rows)]
 
     def _launch(self, reqs: list[ImageRequest],
                 bucket: Optional[int] = None) -> list[ImageRequest]:

@@ -13,6 +13,7 @@ plane-parallel geometries.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,13 @@ def compile_chain(plans, batch, sharding):
     return jax.jit(chain).lower(x, *ws).compile().as_text()
 
 
+def kernel_names(hlo: str) -> list[str]:
+    """Each kernel's HLO instruction name, less its numeric suffix: the
+    ``pallas_call`` name that finds the kernel in a profile."""
+    return re.findall(r"%([A-Za-z_]+)(?:\.\d+)? = [^\n]*" + re.escape(_KERNEL),
+                      hlo)
+
+
 def assert_all_pallas(plans, batch):
     for plan in plans:
         route = plan.route_for_batch(batch)
@@ -101,6 +109,7 @@ def test_dcgan_generator_kernels_compile(one_chip, batch):
     assert_all_pallas(plans, batch)
     hlo = compile_chain(plans, batch, one_chip)
     assert hlo.count(_KERNEL) == len(plans)
+    assert kernel_names(hlo) == ["untangled_deconv"] * len(plans)
 
 
 @pytest.mark.parametrize("batch", BATCH_BUCKETS)
@@ -110,6 +119,7 @@ def test_dcgan_discriminator_kernels_compile(one_chip, batch):
     assert_all_pallas(plans, batch)
     hlo = compile_chain(plans, batch, one_chip)
     assert hlo.count(_KERNEL) == len(plans)
+    assert kernel_names(hlo) == ["untangled_conv"] * len(plans)
 
 
 def test_dcgan_int8_generator_kernels_compile(one_chip):
@@ -144,3 +154,6 @@ def test_tiled_kernels_compile(one_chip, site):
         assert route.path == "pallas" and route.sp_tiles is not None, route
     hlo = compile_chain((plan,), 1, one_chip)
     assert hlo.count(_KERNEL) == 1
+    assert kernel_names(hlo) == [
+        "untangled_deconv_tiled" if plan.spec.kind == "transposed"
+        else "untangled_conv_tiled"]
