@@ -193,6 +193,7 @@ def route_to_json(route: Route) -> dict:
         "sp_tiles": list(route.sp_tiles) if route.sp_tiles else None,
         "dev_tiles": list(route.dev_tiles) if route.dev_tiles else None,
         "fused_bwd": route.fused_bwd,
+        "b_tile": route.b_tile,
     }
 
 
@@ -202,7 +203,8 @@ def route_from_json(d: dict) -> Route:
         tiles=tuple(d["tiles"]) if d.get("tiles") else None,
         fused_bwd=bool(d.get("fused_bwd", True)),
         sp_tiles=tuple(d["sp_tiles"]) if d.get("sp_tiles") else None,
-        dev_tiles=tuple(d["dev_tiles"]) if d.get("dev_tiles") else None)
+        dev_tiles=tuple(d["dev_tiles"]) if d.get("dev_tiles") else None,
+        b_tile=int(d.get("b_tile", 1)))
 
 
 def cache_path(path: Optional[str] = None) -> Optional[str]:
@@ -374,7 +376,7 @@ class AutotunePolicy:
 def _dedupe(routes: Sequence[Route]) -> tuple[Route, ...]:
     seen, out = set(), []
     for r in routes:
-        k = (r.path, r.tiles, r.sp_tiles, r.dev_tiles)
+        k = (r.path, r.tiles, r.sp_tiles, r.dev_tiles, r.b_tile)
         if k not in seen:
             seen.add(k)
             out.append(r)
@@ -472,6 +474,8 @@ def route_label(route: Route) -> str:
         lab += f"@sp{route.sp_tiles[0]}x{route.sp_tiles[1]}"
     if route.dev_tiles:
         lab += f"@dev{route.dev_tiles[0]}x{route.dev_tiles[1]}"
+    if route.b_tile != 1:
+        lab += f"@bt{route.b_tile}"
     return lab
 
 

@@ -178,18 +178,23 @@ def pick_vmem_tiles(hp, wp, c, n, r, s, oh, ow, itemsize, witemsize=None):
 
 
 def pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow, tap_rows,
-                     itemsize, witemsize=None):
-    """(C_t, N_t) for the multi-phase fused kernel, or None: the working
-    set is the whole global plane + the superpack tile + per-phase f32
-    scratch + the full interleaved output block + the largest phase's
-    ``tap_rows``-row tap GEMM."""
+                     itemsize, witemsize=None, batch=1):
+    """(C_t, N_t, B_t) for the multi-phase fused kernel, or None: the
+    working set is the whole global plane + the superpack tile + per-phase
+    f32 scratch + the full interleaved output block + the largest phase's
+    ``tap_rows``-row tap GEMM, every term but the superpack tile times the
+    ``B_t`` images of a grid step.  ``B_t`` is the largest divisor of the
+    bucket ``batch`` whose working set fits the budget — the most images
+    that share one fetch of each superpack tile; None when one image does
+    not fit."""
     from repro.kernels.untangled_conv import (lane_tile,
                                               vmem_bytes_estimate_fused)
     c_t, n_t = lane_tile(c), lane_tile(n)
-    if vmem_bytes_estimate_fused(hg, wg, c_t, total_taps, n_t, sum_uv,
-                                 oh, ow, tap_rows, itemsize,
-                                 witemsize=witemsize) <= _VMEM_BUDGET:
-        return c_t, n_t
+    for b_t in range(batch, 0, -1):
+        if batch % b_t == 0 and vmem_bytes_estimate_fused(
+                hg, wg, c_t, total_taps, n_t, sum_uv, oh, ow, tap_rows,
+                itemsize, witemsize=witemsize, b_tile=b_t) <= _VMEM_BUDGET:
+            return c_t, n_t, b_t
     return None
 
 
@@ -402,6 +407,10 @@ class Route:
     materialize its ``(B, OH, OW, ΣT, ·)`` f32 buffers (one wide dy GEMM +
     one stacked dK GEMM) or must fall back to per-tap GEMMs.
 
+    ``b_tile`` is the images per grid step of the whole-plane fused
+    transposed kernel (``B_t``: each superpack tile is fetched once per
+    ``B_t`` images); 1 on every other route, which is the per-image grid.
+
     ``sp_tiles`` is the spatial output-tile shape when the 'pallas' route is
     the *tiled* kernel — ``(T_oh, T_ow)`` output pixels for the single-
     correlation kinds, ``(T_u, T_v)`` phase-output pixels for the transposed
@@ -431,6 +440,7 @@ class Route:
     fused_bwd: bool = True
     sp_tiles: Pair | None = None  # spatial tile when 'pallas' is tiled
     dev_tiles: Pair | None = None  # (D_h, D_w) plane-parallel verdict
+    b_tile: int = 1               # images per step of the fused deconv
 
 
 def _dev_verdict(spec: ConvSpec, out_hw: Pair, itemsize: int,
@@ -645,9 +655,11 @@ def pallas_transposed_routes(spec: ConvSpec, hg: int, wg: int, out_hw: Pair,
     tap_rows = max(ex.out_hw[0] * ex.out_hw[1] for ex in phases)
     routes = []
     tiles = pick_fused_tiles(hg, wg, c, n, total_taps, sum_uv, oh, ow,
-                             tap_rows, itemsize, witemsize=witemsize)
+                             tap_rows, itemsize, witemsize=witemsize,
+                             batch=batch)
     if tiles is not None:
-        routes.append(Route(batch, "pallas", tiles))
+        c_t, n_t, b_t = tiles
+        routes.append(Route(batch, "pallas", (c_t, n_t), b_tile=b_t))
     tileable = (uniform and oh % spec.strides[0] == 0
                 and ow % spec.strides[1] == 0)
     if tileable:
@@ -1087,10 +1099,14 @@ def _exec_phase(xp: jax.Array, sub4: jax.Array, path: str, tiles: Pair | None,
 
 # -- transposed: fused single-launch executors ------------------------------
 
-def _global_plane(plan: ConvPlan, x4: jax.Array) -> jax.Array:
+def _global_plane(plan: ConvPlan, x4: jax.Array,
+                  batch: int | None = None) -> jax.Array:
+    """``x4`` padded to the global plane, and with zero images up to
+    ``batch`` when that is given."""
     (glh, ghh), (glw, ghw) = plan.gpad
-    if glh or ghh or glw or ghw:
-        return jnp.pad(x4, ((0, 0), (glh, ghh), (glw, ghw), (0, 0)))
+    bpad = 0 if batch is None else batch - x4.shape[0]
+    if bpad or glh or ghh or glw or ghw:
+        return jnp.pad(x4, ((0, bpad), (glh, ghh), (glw, ghw), (0, 0)))
     return x4
 
 
@@ -1247,7 +1263,10 @@ def _transposed_fwd(plan: ConvPlan, x, packed, interpret=None):
         # the global plane below
         y = _pixel_shuffle_fwd(plan, x4, _deq(packed)).astype(x.dtype)
         return y.reshape(lead + y.shape[1:])
-    xg = _global_plane(plan, x4)
+    # B_t divides the route's bucket, not every batch the bucket takes: the
+    # fused kernel runs whole batch blocks of a zero-padded batch (never
+    # past the bucket its working set was sized for)
+    xg = _global_plane(plan, x4, -(-b // route.b_tile) * route.b_tile)
     if path == "pallas":
         from repro.kernels.untangled_conv import untangled_deconv2d_pallas
         quant = isinstance(packed, QuantizedSuperpack)
@@ -1257,7 +1276,8 @@ def _transposed_fwd(plan: ConvPlan, x, packed, interpret=None):
             phases=plan.phases, out_hw=plan.out_hw,
             strides=spec.strides, sum_uv=plan.sum_uv,
             c_tile=route.tiles[0], n_tile=route.tiles[1],
-            sp_tiles=route.sp_tiles, out_dtype=x.dtype, interpret=interpret)
+            b_tile=route.b_tile, sp_tiles=route.sp_tiles, out_dtype=x.dtype,
+            interpret=interpret)[:b]
     elif path in ("fused_tap", "fused_plane"):
         fwd = _fused_tap_fwd if path == "fused_tap" else _fused_plane_fwd
         outs = fwd(plan, xg, _deq(packed))

@@ -7,7 +7,7 @@ NHWC input with an HWIO kernel as the paper's §3.2 sum of per-tap 1x1 convs:
 
 TPU mapping decisions (the HUGE2 "cache locality" story, restated for VMEM/MXU):
 
-* the whole (padded) spatial plane of one batch item lives in VMEM for the
+* the whole (padded) spatial plane of a batch item lives in VMEM for the
   duration of a (C_t, N_t) tile — every tap re-reads it from VMEM, never HBM.
   Edge-generative workloads have small planes (4..64 px) and fat channels,
   exactly the regime where this blocking wins (paper §4.1).
@@ -35,7 +35,15 @@ the **interleaved** output block directly with strided in-kernel stores —
 no per-phase launches, no per-phase input copies, no stack/transpose
 interleave pass.
 
-Grid: ``(B, N/N_t, C/C_t)`` — C innermost (reduction).
+Grid: ``(B/B_t, N/N_t, C/C_t)`` — C innermost (reduction).  The fused
+kernel blocks the batch: ``B_t`` images share one VMEM residency of each
+superpack tile, so a launch fetches every tile ``B/B_t`` times instead of
+``B`` times.  The plan layer picks ``B_t`` (``Route.b_tile``): the largest
+divisor of the batch bucket whose working set fits the VMEM budget, and
+pads a smaller batch to whole blocks.  A block runs in chunks of ``B_c``
+images (``deconv_chunk``), each tap's MXU dot multiplying ``B_c·U·V`` rows
+by the resident ``(C_t, N_t)`` panel; past ``UNROLL_CHUNKS`` chunks a loop
+runs them, so compile time does not grow with ``B_t``.
 
 **Spatially tiled variants** (``sp_tiles`` on both public entries): when the
 whole padded plane does not fit VMEM, the grid grows ``(oh_tiles, ow_tiles)``
@@ -70,6 +78,19 @@ Pair = tuple[int, int]
 # lanes of one vreg: the channel tile of every route (``lane_tile``)
 LANES = 128
 
+# rows of the largest tap dot the fused deconv kernel unrolls per chunk of
+# its batch block (``deconv_chunk``).  Mosaic's compile time grows faster
+# than the unrolled rows.  On a TPU v5e at B64, 512-row chunks leave the
+# Table-1 dc0 site two chunks, which unroll and compile in 10.3 s (1.1 s at
+# 256 rows); 128-row chunks run dc1 7% slower than 256 (0.242 / 0.226 ms).
+CHUNK_ROWS = 256
+
+# a batch block of at most this many chunks unrolls them: the chunk loop
+# keeps one chunk's tap dots from overlapping the next chunk's.  On a TPU
+# v5e at B64 the dc3 site's two one-image chunks run 0.665 ms unrolled and
+# 0.704 ms looped, for 0.9-1.6 s more compile
+UNROLL_CHUNKS = 2
+
 # Mosaic's scoped-VMEM limit for every launch here.  A v5e core has
 # 128 MiB of VMEM; the compiler's default scope is 16 MiB, which the
 # double-buffered whole-plane blocks of real decoder layers outgrow.
@@ -99,11 +120,12 @@ def _tap_panel(k_ref, s_ref, t: int):
     return panel.astype(jnp.float32) * s_ref[t]
 
 
-def _tap_dot(acc_ref, rows, xs, k_ref, s_ref, t: int):
-    """``acc[rows] += xs @ panel_t`` — one tap's MXU product, f32."""
-    acc_ref[rows, :] += jnp.dot(xs.reshape(-1, xs.shape[-1]),
-                                _tap_panel(k_ref, s_ref, t),
-                                preferred_element_type=jnp.float32)
+def _tap_dot(acc_ref, rows: tuple, xs, k_ref, s_ref, t: int):
+    """``acc[rows] += xs @ panel_t`` — one tap's MXU product, f32.  ``rows``
+    indexes every accumulator dim but the lanes."""
+    acc_ref[(*rows, slice(None))] += jnp.dot(
+        xs.reshape(-1, xs.shape[-1]), _tap_panel(k_ref, s_ref, t),
+        preferred_element_type=jnp.float32)
 
 
 def _single_taps(load, acc_ref, k_ref, s_ref, taps_hw: Pair,
@@ -114,7 +136,7 @@ def _single_taps(load, acc_ref, k_ref, s_ref, taps_hw: Pair,
     dh, dw = dilation
     for m in range(r):                 # static tap unroll -> MXU matmul chain
         for n in range(s):
-            _tap_dot(acc_ref, slice(None), load(m * dh, n * dw), k_ref,
+            _tap_dot(acc_ref, (slice(None),), load(m * dh, n * dw), k_ref,
                      s_ref, m * s + n)
 
 
@@ -403,39 +425,77 @@ def untangled_conv2d_pallas(x: jax.Array, kernel: jax.Array, *,
 def _deconv_kernel(x_ref, k_ref, *rest, phases, strides: Pair,
                    n_c_tiles: int):
     """Multi-phase transposed conv: every phase's taps over one VMEM
-    residency of the padded plane, flushed as direct interleaved writes.
+    residency of ``B_t`` padded planes, flushed as direct interleaved
+    writes.
 
     ``phases`` is a static tuple of per-phase records
     ``(q_h, q_w, tap_off, T_h, T_w, xoff_h, xoff_w, U, V, acc_off)`` — all
-    plan-time constants, so the loop fully unrolls into an MXU matmul chain.
+    plan-time constants, so the tap loop fully unrolls into an MXU matmul
+    chain.  The ``B_t`` images of the block run in chunks of ``B_c``
+    (``deconv_chunk``): chunk ``i`` owns ``acc[i]``, phase ``q``'s segment
+    of it ``B_c·U·V`` rows from ``B_c·acc_off`` (image-major inside the
+    phase), so one dot per tap covers the chunk against the resident
+    panel.  A block of more than ``UNROLL_CHUNKS`` chunks loops over them,
+    which keeps the unrolled code one chunk long; a shorter one unrolls.
     An int8 superpack rides with a third input ref of per-tap-row scales
     (see ``_tap_panel``).
     """
     s_ref, o_ref, acc_ref = rest if len(rest) == 3 else (None, *rest)
     sh, sw = strides
+    n_chunks, bc = acc_ref.shape[0], x_ref.shape[0] // acc_ref.shape[0]
     ci = pl.program_id(2)
+
+    def chunks(body):
+        if n_chunks <= UNROLL_CHUNKS:
+            for i in range(n_chunks):
+                body(i)
+            return
+
+        def step(i, carry):
+            body(i)
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, step, 0)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    for (qh, qw, tap_off, th, tw, xh, xw, u, v, acc_off) in phases:
-        if th * tw == 0 or u * v == 0:
-            continue
-        for t in range(th * tw):       # static tap unroll -> MXU matmuls
-            ti, tj = divmod(t, tw)
-            _tap_dot(acc_ref, pl.ds(acc_off, u * v),
-                     x_ref[0, pl.ds(xh + ti, u), pl.ds(xw + tj, v), :],
-                     k_ref, s_ref, tap_off + t)
+    def taps(i):
+        imgs = pl.ds(i * bc, bc)
+        for (qh, qw, tap_off, th, tw, xh, xw, u, v, acc_off) in phases:
+            if th * tw == 0 or u * v == 0:
+                continue
+            for t in range(th * tw):   # static tap unroll -> MXU matmuls
+                ti, tj = divmod(t, tw)
+                _tap_dot(acc_ref, (i, pl.ds(bc * acc_off, bc * u * v)),
+                         x_ref[imgs, pl.ds(xh + ti, u), pl.ds(xw + tj, v), :],
+                         k_ref, s_ref, tap_off + t)
+
+    chunks(taps)
 
     @pl.when(ci == n_c_tiles - 1)
     def _flush():
-        for (qh, qw, tap_off, th, tw, xh, xw, u, v, acc_off) in phases:
-            if u * v == 0:
-                continue
-            blk = acc_ref[pl.ds(acc_off, u * v), :]
-            o_ref[0, pl.ds(qh, u, sh), pl.ds(qw, v, sw), :] = (
-                blk.reshape(u, v, blk.shape[-1]).astype(o_ref.dtype))
+        def flush(i):
+            imgs = pl.ds(i * bc, bc)
+            for (qh, qw, tap_off, th, tw, xh, xw, u, v, acc_off) in phases:
+                if u * v == 0:
+                    continue
+                blk = acc_ref[i, pl.ds(bc * acc_off, bc * u * v), :]
+                o_ref[imgs, pl.ds(qh, u, sh), pl.ds(qw, v, sw), :] = (
+                    blk.reshape(bc, u, v, blk.shape[-1]).astype(o_ref.dtype))
+
+        chunks(flush)
+
+
+def deconv_chunk(b_tile: int, tap_rows: int) -> int:
+    """``B_c``, the images of one unrolled chunk of the fused kernel's
+    batch block: the largest divisor of ``b_tile`` whose tap dot stays
+    within ``CHUNK_ROWS`` rows (at least one image).  The superpack tile is
+    fetched once per block whatever the chunk; the chunk bounds the code
+    the compiler unrolls, and so its compile time."""
+    return max((d for d in range(1, b_tile + 1)
+                if b_tile % d == 0 and d * tap_rows <= CHUNK_ROWS), default=1)
 
 
 def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
@@ -444,6 +504,7 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
                               scales: jax.Array | None = None,
                               c_tile: int = LANES,
                               n_tile: int = LANES,
+                              b_tile: int = 1,
                               sp_tiles: Pair | None = None, out_dtype=None,
                               interpret: bool | None = None) -> jax.Array:
     """Fused transposed conv: ONE kernel launch for all s_h*s_w phases.
@@ -451,7 +512,9 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
     xg: (B, Hg, Wg, C) globally padded plane; superpack: (ΣT·C, N) tap-major
     phase sub-kernels (``ConvPlan.pack`` layout); ``phases`` the plan's
     ``PhaseExec`` records.  Output (B, out_h, out_w, N), written interleaved
-    inside the kernel — no stack/transpose pass afterwards.
+    inside the kernel — no stack/transpose pass afterwards.  ``b_tile``
+    images share each grid step (and each fetched superpack tile); it must
+    divide B (the plan layer pads the batch to whole blocks).
     ``sp_tiles=(T_u, T_v)`` (phase-output coordinates; uniform phases only)
     selects the spatially tiled grid with halo'd, double-buffered input
     slices instead of whole-plane VMEM residency.  ``scales`` (``(ΣT·C, 1)``
@@ -482,13 +545,19 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
         k3 = jnp.pad(k3, ((0, 0), (0, 0), (0, np_ - n)))
     n_c_tiles = cp // c_tile
 
+    if b % b_tile:
+        raise ValueError(f"b_tile={b_tile} does not divide the batch {b}")
+    chunk = deconv_chunk(b_tile, max(ex.out_hw[0] * ex.out_hw[1]
+                                     for ex in phases))
+
     meta = tuple(
         (ex.q[0], ex.q[1], ex.tap_off, ex.taps[0], ex.taps[1],
          ex.xoff[0], ex.xoff[1], ex.out_hw[0], ex.out_hw[1], ex.acc_off)
         for ex in phases)
-    grid = (b, np_ // n_tile, n_c_tiles)
+    grid = (b // b_tile, np_ // n_tile, n_c_tiles)
     in_specs = [
-        pl.BlockSpec((1, hg, wg, c_tile), lambda b_, n_, c_: (b_, 0, 0, c_)),
+        pl.BlockSpec((b_tile, hg, wg, c_tile),
+                     lambda b_, n_, c_: (b_, 0, 0, c_)),
         pl.BlockSpec((total_taps, c_tile, n_tile),
                      lambda b_, n_, c_: (0, c_, n_)),
     ]
@@ -502,10 +571,11 @@ def untangled_deconv2d_pallas(xg: jax.Array, superpack: jax.Array, *,
                           n_c_tiles=n_c_tiles),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, oh, ow, n_tile),
+        out_specs=pl.BlockSpec((b_tile, oh, ow, n_tile),
                                lambda b_, n_, c_: (b_, 0, 0, n_)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((sum_uv, n_tile), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((b_tile // chunk, chunk * sum_uv, n_tile),
+                                   jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         name="untangled_deconv",
         interpret=interpret,
@@ -551,7 +621,7 @@ def _deconv_tiled_kernel(x_any, k_ref, *rest, phases,
     for pi, (qh, qw, tap_off, th, tw, xh, xw) in enumerate(phases):
         for t in range(th * tw):        # static tap unroll -> MXU matmuls
             ti, tj = divmod(t, tw)      # (an empty phase's acc stays zero)
-            _tap_dot(acc_ref, pl.ds(pi * tu * tv, tu * tv),
+            _tap_dot(acc_ref, (pl.ds(pi * tu * tv, tu * tv),),
                      buf[slot, pl.ds(xh - mh + ti, tu),
                          pl.ds(xw - mw + tj, tv), :],
                      k_ref, s_ref, tap_off + t)
@@ -689,13 +759,20 @@ def vmem_bytes_estimate_superpack(hp, wp, c_tile, total_taps, n_tile,
 
 
 def vmem_bytes_estimate_fused(hg, wg, c_tile, total_taps, n_tile, sum_uv,
-                              oh, ow, tap_rows, itemsize=4, witemsize=None):
-    """Working set of the fused multi-phase kernel: global plane block +
-    superpack tile + full interleaved output block, the ``sum_uv``-row
-    per-phase f32 accumulator, and the largest phase's ``tap_rows = U·V``
-    tap GEMM."""
-    return _working_set((hg, wg), c_tile, total_taps, n_tile, (oh, ow),
-                        sum_uv, tap_rows, itemsize, witemsize)
+                              oh, ow, tap_rows, itemsize=4, witemsize=None,
+                              b_tile=1):
+    """Working set of the fused multi-phase kernel at ``b_tile`` images per
+    grid step: ``b_tile`` global plane blocks + the superpack tile (shared
+    by the batch block) + ``b_tile`` full interleaved output blocks, the
+    per-phase f32 accumulator (one ``B_c·sum_uv``-row slab per chunk of
+    ``B_c`` images, ``deconv_chunk``), and the largest phase's
+    ``B_c·tap_rows`` (``tap_rows = U·V``) tap GEMM."""
+    chunk = deconv_chunk(b_tile, tap_rows)
+    # one f32 slab per chunk, each padded to the 8-sublane tile
+    acc_rows = b_tile // chunk * (-(-chunk * sum_uv // 8) * 8)
+    return _working_set((b_tile * hg, wg), c_tile, total_taps, n_tile,
+                        (b_tile * oh, ow), acc_rows, chunk * tap_rows,
+                        itemsize, witemsize)
 
 
 def vmem_bytes_estimate_tiled(tin_h, tin_w, c_tile, total_taps, n_tile,

@@ -126,10 +126,22 @@ def test_route_json_schema_matches_fixture():
     r = Route(4, "pallas", (8, 8), sp_tiles=(4, 4), fused_bwd=False)
     rj = route_to_json(r)
     assert set(rj) == {"batch", "path", "tiles", "sp_tiles", "fused_bwd",
-                       "dev_tiles"}
+                       "dev_tiles", "b_tile"}
     assert route_from_json(rj) == r
     dev = Route(16, "pallas", (8, 8), sp_tiles=(4, 4), dev_tiles=(2, 2))
     assert route_from_json(route_to_json(dev)) == dev
+
+
+def test_route_batch_tile_roundtrip_and_label():
+    """``Route.b_tile`` survives the cache's JSON and shows in the label; a
+    cached record from before the field loads as one image per step."""
+    r = Route(64, "pallas", (128, 128), b_tile=32)
+    assert route_from_json(route_to_json(r)) == r
+    assert route_label(r) == "pallas@128x128@bt32"
+    assert route_label(Route(1, "pallas", (128, 128))) == "pallas@128x128"
+    old = route_to_json(r)
+    del old["b_tile"]
+    assert route_from_json(old) == Route(64, "pallas", (128, 128))
 
 
 @pytest.mark.parametrize("poison", ["corrupt", "truncated", "stale_schema",

@@ -12,7 +12,8 @@ from repro.core import reference as ref
 from repro.core.plan import ConvSpec, conv_spec, plan_conv
 from repro.models.gan import DCGAN_LAYERS
 
-from tests.conftest import assert_close, count_eqns, vmem_slab
+from tests.conftest import (assert_close, assert_close_ulp, count_eqns,
+                            vmem_slab)
 
 
 # ---------------------------------------------------------------------------
@@ -220,3 +221,111 @@ def test_bf16_plan_picks_tiles_accounting_f32_scratch():
                                     plan.sum_uv, *plan.out_hw, tap_rows,
                                     itemsize=2)
     assert est <= planmod._VMEM_BUDGET
+
+
+# ---------------------------------------------------------------------------
+# batch-blocked grid: B_t images share one fetch of each superpack tile
+# ---------------------------------------------------------------------------
+
+def _deconv_case(b, h, c, n, k, s, pads, wdtype="float32", seed=0):
+    """(plan, x, kernel, packed) of one transposed site on the Pallas
+    backend; an int8 site's kernel is its dequantized superpack."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    x = jax.random.normal(k1, (b, h, h, c), jnp.float32)
+    kern = jax.random.normal(k2, (k, k, c, n), jnp.float32)
+    plan = plan_conv(conv_spec("transposed", x.shape, kern.shape,
+                               strides=(s, s), padding=pads,
+                               backend="pallas", wdtype=wdtype))
+    packed = plan.pack(kern)
+    if wdtype == "int8":
+        kern = plan.unpack(packed)
+    return plan, x, kern, packed
+
+
+def _deconv_blocked(plan, x, packed, b_tile, c_tile):
+    """The fused kernel on the plan's global plane at ``b_tile`` images
+    per grid step and ``c_tile`` channels per reduction step."""
+    import repro.core.plan as planmod
+    from repro.kernels.untangled_conv import untangled_deconv2d_pallas
+    quant = isinstance(packed, planmod.QuantizedSuperpack)
+    return untangled_deconv2d_pallas(
+        planmod._global_plane(plan, x), packed.q if quant else packed,
+        scales=packed.scale if quant else None, phases=plan.phases,
+        out_hw=plan.out_hw, strides=plan.spec.strides, sum_uv=plan.sum_uv,
+        c_tile=c_tile, b_tile=b_tile, interpret=True)
+
+
+# (in_hw, C, N, k, stride, pads, wdtype), every site over two C tiles: the
+# DCGAN k5 s2 site with narrowed channels; a k4 s2 site with an odd output
+# (non-uniform phases: 5x5 and 4x4 rows); the DCGAN site on an int8
+# superpack
+BLOCKED_SITES = {
+    "dcgan_k5s2": (4, 32, 8, 5, 2, ((2, 3), (2, 3)), "float32"),
+    "k4s2_odd_out": (5, 32, 8, 4, 2, ((1, 2), (1, 2)), "float32"),
+    "dcgan_k5s2_int8": (4, 32, 8, 5, 2, ((2, 3), (2, 3)), "int8"),
+}
+
+
+@pytest.mark.parametrize("site,b,b_tile,chunk_rows", [
+    ("dcgan_k5s2", 4, 1, None), ("dcgan_k5s2", 4, 2, None),
+    ("dcgan_k5s2", 4, 4, None), ("dcgan_k5s2", 8, 2, None),
+    ("dcgan_k5s2", 8, 4, None),
+    # chunks of two images: two per batch block unroll, four loop
+    ("dcgan_k5s2", 8, 4, 32), ("dcgan_k5s2", 8, 8, 32),
+    ("k4s2_odd_out", 8, 8, 50), ("dcgan_k5s2_int8", 8, 8, 32),
+])
+def test_batch_blocked_deconv_matches_oracle(site, b, b_tile, chunk_rows,
+                                             monkeypatch):
+    """Every one of the B/B_t batch blocks, and every chunk of a block,
+    against the lax oracle and within the f32 rounding bound of the
+    float64 one."""
+    import repro.kernels.untangled_conv as uc
+    from tests.test_tiled_kernels import deconv_oracle_f64
+    if chunk_rows is not None:
+        monkeypatch.setattr(uc, "CHUNK_ROWS", chunk_rows)
+    h, c, n, k, s, pads, wdtype = BLOCKED_SITES[site]
+    plan, x, kern, packed = _deconv_case(b, h, c, n, k, s, pads, wdtype,
+                                         seed=b * 10 + b_tile)
+    assert plan.uniform == (site != "k4s2_odd_out")
+    tap_rows = max(ex.out_hw[0] * ex.out_hw[1] for ex in plan.phases)
+    if chunk_rows is not None:
+        assert uc.deconv_chunk(b_tile, tap_rows) == 2
+    got = _deconv_blocked(plan, x, packed, b_tile, c_tile=c // 2)
+    assert got.shape == (b, *plan.out_hw, n)
+    y64, amax64 = deconv_oracle_f64(x, kern, strides=(s, s), pads=pads)
+    assert_close_ulp(got, y64, amax64, n_terms=k * k * c)
+    assert_close(got, ref.oracle_conv_transpose2d(
+        x, kern, strides=(s, s), padding=pads))
+
+
+@pytest.mark.parametrize("b", [3, 6])
+def test_partial_batch_runs_whole_batch_blocks(b, monkeypatch):
+    """A batch below its bucket that the route's ``B_t`` does not divide
+    runs whole batch blocks of a zero-padded batch (never a smaller
+    ``B_t``), and only its own images come back."""
+    import repro.kernels.untangled_conv as uc
+    h, c, n, k, s, pads, wdtype = BLOCKED_SITES["dcgan_k5s2"]
+    plan, x, kern, packed = _deconv_case(b, h, c, n, k, s, pads, seed=b)
+    route = plan.route_for_batch(b)
+    assert route.path == "pallas" and route.b_tile > 1 and b % route.b_tile
+    launched = []
+    kernel = uc.untangled_deconv2d_pallas
+
+    def spy(xg, *args, **kw):
+        launched.append((xg.shape[0], kw["b_tile"]))
+        return kernel(xg, *args, **kw)
+
+    monkeypatch.setattr(uc, "untangled_deconv2d_pallas", spy)
+    got = plan.apply(x, packed)
+    assert launched == [(-(-b // route.b_tile) * route.b_tile, route.b_tile)]
+    assert got.shape == (b, *plan.out_hw, n)
+    assert_close(got, ref.oracle_conv_transpose2d(
+        x, kern, strides=(s, s), padding=pads))
+
+
+def test_fused_kernel_refuses_a_ragged_batch_block():
+    """``b_tile`` must divide the batch: the plan layer pads, the kernel
+    does not pick another block."""
+    plan, x, _, packed = _deconv_case(6, *BLOCKED_SITES["dcgan_k5s2"])
+    with pytest.raises(ValueError, match="does not divide"):
+        _deconv_blocked(plan, x, packed, b_tile=4, c_tile=16)

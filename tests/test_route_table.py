@@ -81,3 +81,57 @@ def test_every_pallas_verdict_has_lane_aligned_tiles():
             assert c_t == want_c, (e["name"], r)
             n_checked += 1
     assert n_checked > 0
+
+
+def test_dcgan_batch_tiles_follow_the_vmem_rule():
+    """The fused deconv's ``B_t`` is the largest divisor of the bucket whose
+    working set fits the VMEM budget: 64 / 32 / 8 / 2 images per grid step
+    for the Table-1 generator's dc0-dc3 at B64, one at B1 (the per-image
+    grid), and every chosen working set within the budget."""
+    import dataclasses
+
+    import repro.core.plan as planmod
+    from repro.kernels.untangled_conv import vmem_bytes_estimate_fused
+    from repro.models import gan
+
+    plans = gan.generator_plans(dataclasses.replace(gan.DCGAN,
+                                                    backend="pallas"))
+    assert [p.route_for_batch(64).b_tile for p in plans] == [64, 32, 8, 2]
+    for plan in plans:
+        (glh, ghh), (glw, ghw) = plan.gpad
+        hg = plan.spec.in_hw[0] + glh + ghh
+        wg = plan.spec.in_hw[1] + glw + ghw
+        tap_rows = max(ex.out_hw[0] * ex.out_hw[1] for ex in plan.phases)
+
+        def working_set(route, b_tile):
+            return vmem_bytes_estimate_fused(
+                hg, wg, route.tiles[0], plan.total_taps, route.tiles[1],
+                plan.sum_uv, *plan.out_hw, tap_rows, b_tile=b_tile)
+
+        assert plan.route_for_batch(1).b_tile == 1
+        for route in plan.routes:
+            assert route.path == "pallas" and route.batch % route.b_tile == 0
+            assert working_set(route, route.b_tile) <= planmod._VMEM_BUDGET
+            # the next divisor of the bucket up would not fit
+            bigger = [d for d in range(route.b_tile + 1, route.batch + 1)
+                      if route.batch % d == 0]
+            if bigger:
+                assert working_set(route, bigger[0]) > planmod._VMEM_BUDGET
+
+
+def test_fixture_routes_carry_batch_tiles():
+    """Only the whole-plane fused transposed kernel blocks the batch: every
+    other route of the fixture, and every B1 route, runs one image per
+    grid step."""
+    table = json.loads(pathlib.Path(FIXTURE).read_text())
+    blocked = 0
+    for e in table["entries"]:
+        for r in e["routes"]:
+            fused = (e["spec"]["kind"] == "transposed"
+                     and r["path"] == "pallas" and not r["sp_tiles"])
+            if not fused or r["batch"] == 1:
+                assert r["b_tile"] == 1, (e["name"], r)
+            else:
+                assert r["batch"] % r["b_tile"] == 0, (e["name"], r)
+                blocked += r["b_tile"] > 1
+    assert blocked > 0

@@ -8,7 +8,8 @@ than the scoped limit.  Here each route the planner emits under
 is needed, nothing runs.  The sites are chained per model (one compile per
 model and bucket), at real widths: the Table-1 DCGAN generator and its
 discriminator at every batch bucket (and the generator once with int8
-weights), SegNet, and the spatially tiled kernels of the two
+weights), the VAE decoder's and the U-Net's transposed sites at the
+largest bucket, SegNet, and the spatially tiled kernels of the two
 plane-parallel geometries.
 """
 import dataclasses
@@ -22,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.core.plan as planmod
 from repro.core.plan import BATCH_BUCKETS, plan_conv
-from repro.models import gan, segnet
+from repro.models import gan, segnet, unet, vae
 
 _KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -105,8 +106,15 @@ DCGAN = dataclasses.replace(gan.DCGAN, backend="pallas")
 
 @pytest.mark.parametrize("batch", BATCH_BUCKETS)
 def test_dcgan_generator_kernels_compile(one_chip, batch):
+    """Every bucket's fused deconv kernels, batch-blocked above B1: the
+    B64 grid steps over 64 / 32 / 8 / 2 images of dc0-dc3."""
     plans = gan.generator_plans(DCGAN)
     assert_all_pallas(plans, batch)
+    b_tiles = [p.route_for_batch(batch).b_tile for p in plans]
+    if batch == 1:
+        assert b_tiles == [1] * len(plans)
+    else:
+        assert all(b_t > 1 and batch % b_t == 0 for b_t in b_tiles), b_tiles
     hlo = compile_chain(plans, batch, one_chip)
     assert hlo.count(_KERNEL) == len(plans)
     assert kernel_names(hlo) == ["untangled_deconv"] * len(plans)
@@ -129,6 +137,40 @@ def test_dcgan_int8_generator_kernels_compile(one_chip):
     assert_all_pallas(plans, 1)
     hlo = compile_chain(plans, 1, one_chip)
     assert hlo.count(_KERNEL) == len(plans)
+
+
+def _transposed_plans(model: str):
+    if model == "vae_decoder":
+        return vae.decoder_plans(dataclasses.replace(vae.VAE,
+                                                     backend="pallas"))
+    plans = unet.unet_plans(dataclasses.replace(unet.UNET, backend="pallas"))
+    return tuple(p for p in plans.values() if p.spec.kind == "transposed")
+
+
+@pytest.mark.parametrize("model", ("vae_decoder", "unet_up"))
+def test_transposed_sites_compile_at_largest_bucket(one_chip, model):
+    """The other fused deconv sites, each at its largest bucket's batch
+    block: the VAE decoder and the U-Net's transposed ups."""
+    batch = BATCH_BUCKETS[-1]
+    plans = _transposed_plans(model)
+    assert plans
+    assert_all_pallas(plans, batch)
+    for plan in plans:
+        route = plan.route_for_batch(batch)
+        assert route.sp_tiles is None and batch % route.b_tile == 0, route
+        hlo = compile_chain((plan,), batch, one_chip)
+        assert kernel_names(hlo) == ["untangled_deconv"], plan.spec
+
+
+def test_dcgan_int8_generator_batch_blocked_kernels_compile(one_chip):
+    """The int8 superpacks' scale columns ride the batch-blocked grid of
+    the largest bucket as well."""
+    batch = BATCH_BUCKETS[-1]
+    plans = gan.generator_plans(dataclasses.replace(DCGAN, wdtype="int8"))
+    assert_all_pallas(plans, batch)
+    assert all(p.route_for_batch(batch).b_tile > 1 for p in plans)
+    hlo = compile_chain(plans, batch, one_chip)
+    assert kernel_names(hlo) == ["untangled_deconv"] * len(plans)
 
 
 @pytest.mark.parametrize("batch", (1, BATCH_BUCKETS[-1]))
