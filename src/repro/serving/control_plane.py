@@ -28,26 +28,36 @@ admission → schedule → launch → replay
   are **shed before launch** (never compute something the client stopped
   waiting for) and counted separately from served ones.
 - **Launch**: image models go through the backend's bucket executables
-  (``DynamicImageBatcher.execute`` — plans pre-built at model load, bucket
-  costs shared via the ``RouteCache``); LM models advance one
-  ``ContinuousBatcher`` decode step per pump.  Every launch wall-time
-  feeds a per-(model, bucket) ``StragglerMonitor``; flagged buckets
-  surface as the slow-bucket alert in ``stats()``.  Each launch stamps its
-  requests with ``launch_seq`` and ``t_launch``, so queue wait
-  (``t_launch - t_arrival``) and service (``t_done - t_launch``) are read
-  per request.
+  (``DynamicImageBatcher.start`` / ``finish`` — plans pre-built at model
+  load, bucket costs shared via the ``RouteCache``); LM models advance one
+  ``ContinuousBatcher`` decode step per pump.  An image launch is started
+  (stack, H2D, dispatch, the output's copy to the host requested) and
+  finished (wait, the live rows on the host) in two halves, and at most
+  one started launch stays in flight across a pump: a pump starts the due
+  launch, and a second one if another is due at once (a full queue's cold
+  start), then finishes launches oldest first until one is left — or
+  none, if it finished no other.  So a lone launch is answered by its own
+  pump, and under a backlog the device runs launch k+1 while launch k's
+  answer copies to the host.  The rule reads only the queues, never the
+  model.  Every launch's wall-time, start to answers in hand, feeds a
+  per-(model, bucket) ``StragglerMonitor``; flagged buckets surface as the
+  slow-bucket alert in ``stats()``.  Each start stamps its requests with
+  ``launch_seq`` and ``t_launch``, so queue wait (``t_launch -
+  t_arrival``) and service (``t_done - t_launch``) are read per request.
 - **Replay** (the fault ladder): a ``FailureInjector`` (or a real
-  ``NodeFailure``) firing at a launch boundary kills that launch's
-  results.  The control plane re-queues the affected live requests at the
-  *front* of their class queues in arrival order and replays them on the
-  next pump — zero requests dropped, zero answered twice, and (the
+  ``NodeFailure``) firing at a launch's start or finish kills that
+  launch's results alone; a launch already in flight is finished as
+  usual.  The control plane re-queues the dead launch's live requests at
+  the *front* of their class queues in arrival order and replays them on
+  a later pump — zero requests dropped, zero answered twice, and (the
   relaunches hit the same bucket executables on the same payloads)
   responses bit-equal to a fault-free run — asserted in
   ``tests/test_control_plane.py``.  When the failure means a lost replica,
-  ``degrade`` shrinks the mesh via ``runtime.elastic.shrink_mesh`` and
-  re-jits every image backend under the surviving data-parallel extent;
-  with ``spatial_tiles=`` it instead re-tiles the survivors as a spatial
-  mesh and re-plans plane-parallel ``dev_tiles`` (``core.spatial``).
+  ``degrade`` finishes every launch in flight, then shrinks the mesh via
+  ``runtime.elastic.shrink_mesh`` and re-jits every image backend under
+  the surviving data-parallel extent; with ``spatial_tiles=`` it instead
+  re-tiles the survivors as a spatial mesh and re-plans plane-parallel
+  ``dev_tiles`` (``core.spatial``).
 
 Multi-model hosting: ``register_image_model`` / ``register_lm_model`` put
 a GAN, a segnet, and a VAE (or anything with a ``serve_fn``) behind one
@@ -56,15 +66,18 @@ and the batchers share one ``RouteCache`` for measured bucket costs.
 
 ``stats()`` reports per-class p50/p95/p99, **goodput under SLO** (served
 within deadline / submitted — rejected, shed, and served-but-late all
-count against it), fault/replay records, and the straggler alert; the
+count against it), fault/replay records, the straggler alert, and
+``launches_overlapped`` (image launches started while another was in
+flight: how often the pipeline engages); the
 open-loop tail-latency harness in ``benchmarks/serve_bench.py`` turns the
 same report into ``BENCH_slo.json``.
 
 Tracing: schedule and launch are ``jax.profiler.TraceAnnotation`` spans
-(``huge2.schedule``, ``huge2.launch`` and, inside the batcher, its
-``huge2.launch.*`` steps), recorded only while a profiler traces the
-process and on the same clock as the device's operations
-(docs/ARCHITECTURE.md, "Tracing").
+(``huge2.schedule``, ``huge2.launch`` around a launch's start and, inside
+the batcher, its ``huge2.launch.*`` steps; ``wait`` and ``d2h`` fall in
+the launch's finish, which may come a pump later), recorded only while a
+profiler traces the process and on the same clock as the device's
+operations (docs/ARCHITECTURE.md, "Tracing").
 """
 from __future__ import annotations
 
@@ -81,7 +94,7 @@ from jax.profiler import TraceAnnotation
 from repro.core.plan import BATCH_BUCKETS
 from repro.runtime.fault import NodeFailure, StragglerMonitor
 from repro.serving.batcher import ContinuousBatcher, Request
-from repro.serving.image_batcher import DynamicImageBatcher
+from repro.serving.image_batcher import DynamicImageBatcher, InFlight
 from repro.serving.metrics import latency_stats
 
 PRIORITIES = ("interactive", "batch")
@@ -106,8 +119,9 @@ class ServeRequest:
     # None = stamped by the control plane's injected clock at submit
     # (deadline tests / open-loop drivers stamp explicitly, same domain)
     t_arrival: Optional[float] = None
-    # stamped at launch (image backends): the control plane's launch_seq
-    # of the launch that answered, and its start on the same clock
+    # stamped at a launch's start (image backends): the control plane's
+    # launch_seq of the launch that answered, and its start on the same
+    # clock
     launch_seq: Optional[int] = None
     t_launch: Optional[float] = None
     t_done: Optional[float] = None
@@ -148,8 +162,9 @@ class ImageBackend:
     queue stays empty in this mode)."""
 
     kind = "image"
-    # the control plane's launch_seq of the launch in progress, set before
-    # each ``launch`` (whose signature callers wrap) to label its spans
+    # the control plane's launch_seq of the launch being started, set before
+    # each ``start`` (and ``launch``, whose signature callers wrap) to label
+    # its spans
     launch_seq: Optional[int] = None
 
     def __init__(self, name: str, serve_fn: Callable, proto: np.ndarray, *,
@@ -188,7 +203,15 @@ class ImageBackend:
 
     def launch(self, payloads: Sequence[np.ndarray],
                bucket: int) -> np.ndarray:
+        """One launch, start to finish (the control plane itself drives
+        ``start`` and ``finish`` to keep a launch in flight)."""
         return self.batcher.execute(payloads, bucket, seq=self.launch_seq)
+
+    def start(self, payloads: Sequence[np.ndarray], bucket: int) -> InFlight:
+        return self.batcher.start(payloads, bucket, seq=self.launch_seq)
+
+    def finish(self, launch: InFlight) -> np.ndarray:
+        return self.batcher.finish(launch)
 
     def rebind(self, dist, serve_fn: Optional[Callable] = None):
         self.batcher.rebind_dist(dist, serve_fn)
@@ -270,6 +293,16 @@ class LMBackend:
         return live
 
 
+@dataclasses.dataclass
+class _Started:
+    """An image launch in flight: who runs it, whom it answers, the
+    batcher's handle and the start stamp on the control plane's clock."""
+    be: ImageBackend
+    reqs: list[ServeRequest]
+    launch: InFlight
+    t_launch: float
+
+
 class ControlPlane:
     """Admission + scheduling + fault replay over registered backends.
 
@@ -311,6 +344,10 @@ class ControlPlane:
         self._straggler_kw = dict(k=straggler_k, warmup=straggler_warmup)
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
+        self._in_flight: deque[_Started] = deque()   # oldest first
+        # answered outside the pump that returns them (``degrade``)
+        self._answered: list[ServeRequest] = []
+        self.launches_overlapped = 0
 
     # -- model registration (model load: plans pre-built here) ---------------
     def register_image_model(self, name: str, serve_fn: Callable,
@@ -404,26 +441,42 @@ class ControlPlane:
         return bool(ddls) and min(ddls) - now <= be.max_wait_s
 
     def pump(self, *, drain: bool = False) -> list[ServeRequest]:
-        """One scheduling round: advance every LM backend a step, launch at
-        most one image bucket; returns the requests completed."""
+        """One scheduling round: advance every LM backend a step, start the
+        due image launch (and a second if another is due at once), then
+        finish started launches oldest first until one is left in flight,
+        or none when this round finished no other; returns the requests
+        completed."""
         now = self.clock()
         finished = self._pump_lm(now)
+        if self._start_due(now, drain):
+            self._start_due(now, drain)
+        n = 0
+        while len(self._in_flight) > 1 or (self._in_flight and not n):
+            self._finish_oldest()
+            n += 1
+        finished += self._answered
+        self._answered = []
+        return finished
+
+    def _start_due(self, now: float, drain: bool) -> bool:
+        """Schedule and start the due image launch, if any; ``True`` when
+        one was started."""
         due = [n for n, b in self.backends.items()
                if isinstance(b, ImageBackend) and self._launch_due(n, now,
                                                                    drain)]
-        if due:
-            # EDF across models: earliest head-of-line deadline wins
-            def urgency(name):
-                heads = [c[0] for c in self.queues[name].values() if c]
-                ddl = min((h.deadline for h in heads
-                           if h.deadline is not None), default=float("inf"))
-                return (ddl, min(h.t_arrival for h in heads))
-            with TraceAnnotation("huge2.schedule", seq=self.launch_seq + 1):
-                name = min(due, key=urgency)
-                reqs, size = self._schedule_image(name, now)
-            if reqs:
-                finished += self._execute(self.backends[name], reqs, size)
-        return finished
+        if not due:
+            return False
+
+        # EDF across models: earliest head-of-line deadline wins
+        def urgency(name):
+            heads = [c[0] for c in self.queues[name].values() if c]
+            ddl = min((h.deadline for h in heads
+                       if h.deadline is not None), default=float("inf"))
+            return (ddl, min(h.t_arrival for h in heads))
+        with TraceAnnotation("huge2.schedule", seq=self.launch_seq + 1):
+            name = min(due, key=urgency)
+            reqs, size = self._schedule_image(name, now)
+        return bool(reqs) and self._start(self.backends[name], reqs, size)
 
     def _take(self, name: str, cls: str, want: int,
               now: float) -> list[ServeRequest]:
@@ -473,7 +526,7 @@ class ControlPlane:
                 done = be.step()
                 self._observe(be.name, "step", time.perf_counter() - t0)
             except NodeFailure as e:
-                self._on_failure(be, be.evict_live(), e)
+                self._on_failure(be, be.evict_live(), e, self.launch_seq)
                 continue
             for r in done:
                 self._commit(r)
@@ -481,32 +534,45 @@ class ControlPlane:
         return finished
 
     # -- launch + replay ------------------------------------------------------
-    def _execute(self, be: ImageBackend, reqs: list[ServeRequest],
-                 bucket: int) -> list[ServeRequest]:
+    def _start(self, be: ImageBackend, reqs: list[ServeRequest],
+               bucket: int) -> bool:
         self.launch_seq += 1
         seq = be.launch_seq = self.launch_seq
         t0 = self.clock()
         waits = [t0 - r.t_arrival for r in reqs]
         for r in reqs:
             r.launch_seq, r.t_launch = seq, t0
+        overlapped = int(bool(self._in_flight))
         try:
             if self.injector is not None:
                 self.injector.check(seq)              # device lost mid-batch
             with TraceAnnotation(
                     "huge2.launch", seq=seq, model=be.name, bucket=bucket,
                     live=len(reqs), wait_us_sum=sum(waits) * 1e6,
-                    wait_us_max=max(waits) * 1e6):
-                outs = be.launch([r.payload for r in reqs], bucket)
+                    wait_us_max=max(waits) * 1e6, overlapped=overlapped):
+                launch = be.start([r.payload for r in reqs], bucket)
         except NodeFailure as e:
-            self._on_failure(be, reqs, e)
-            return []
+            self._on_failure(be, reqs, e, seq)
+            return False
+        self.launches_overlapped += overlapped
+        self._in_flight.append(_Started(be, reqs, launch, t0))
+        return True
+
+    def _finish_oldest(self):
+        st = self._in_flight.popleft()
+        try:
+            outs = st.be.finish(st.launch)
+        except NodeFailure as e:
+            self._on_failure(st.be, st.reqs, e, st.launch.seq)
+            return
         t1 = self.clock()
-        self._observe(be.name, bucket, t1 - t0)
-        for r, out in zip(reqs, outs):
+        self._observe(st.be.name, st.launch.bucket, t1 - st.t_launch,
+                      seq=st.launch.seq)
+        for r, out in zip(st.reqs, outs):
             r.out = out
             r.t_done = t1
             self._commit(r)
-        return reqs
+        self._answered += st.reqs
 
     def _commit(self, r: ServeRequest):
         if r.rid in self._served_rids:
@@ -516,13 +582,15 @@ class ControlPlane:
         self.done.append(r)
         self._t_last = self.clock()
 
-    def _on_failure(self, be, live: list[ServeRequest], err: Exception):
-        """The fault ladder, rung one: discard the dead launch, re-queue
-        its live requests at the front of their class queues in arrival
-        order, and replay on the next pump.  Rung two (replica actually
-        lost) is ``degrade``, reachable via the ``on_fault`` hook."""
+    def _on_failure(self, be, live: list[ServeRequest], err: Exception,
+                    seq: int):
+        """The fault ladder, rung one: discard the dead launch ``seq``,
+        re-queue its live requests at the front of their class queues in
+        arrival order, and replay on a later pump.  Rung two (replica
+        actually lost) is ``degrade``, reachable via the ``on_fault``
+        hook."""
         self.fault_events.append({
-            "launch": self.launch_seq, "model": be.name,
+            "launch": seq, "model": be.name,
             "live": len(live), "error": str(err)})
         for r in sorted(live, key=lambda r: (r.t_arrival, r.rid),
                         reverse=True):
@@ -536,12 +604,13 @@ class ControlPlane:
     def degrade(self, devices_left: int, *, model_parallel: int = 1,
                 pod: int = 0, serve_fns: Optional[dict] = None,
                 spatial_tiles: Optional[tuple] = None):
-        """Degraded serving after replica loss: shrink the mesh to the
-        surviving chips and re-jit every image backend under the new
-        extent.  ``serve_fns`` optionally maps model name -> a rebuilt
-        closure over re-placed params (the ``elastic.restore_on_mesh``
-        path); without it the existing closures re-jit under the shrunk
-        mesh.
+        """Degraded serving after replica loss: finish every image launch
+        in flight (its answers come back from the next ``pump``), shrink
+        the mesh to the surviving chips and re-jit every image backend
+        under the new extent.  ``serve_fns`` optionally maps model name ->
+        a rebuilt closure over re-placed params (the
+        ``elastic.restore_on_mesh`` path); without it the existing
+        closures re-jit under the shrunk mesh.
 
         Data-parallel (default): ``runtime.elastic.shrink_mesh`` — TP
         preserved, whole DP replicas dropped.
@@ -555,6 +624,8 @@ class ControlPlane:
         is the re-plan: the new closures trace through the shard_map
         executor on the shrunk mesh."""
         from repro.sharding import DistContext
+        while self._in_flight:
+            self._finish_oldest()
         if spatial_tiles is not None:
             from repro.core import spatial as spatialmod
             from repro.launch.mesh import make_spatial_mesh
@@ -580,11 +651,14 @@ class ControlPlane:
             self.degraded["spatial_tiles"] = (sp_h, sp_w)
         return mesh
 
-    def _observe(self, model: str, bucket, dt: float):
+    def _observe(self, model: str, bucket, dt: float,
+                 seq: Optional[int] = None):
+        """One launch's duration for its (model, bucket) monitor; ``seq``
+        labels a flagged launch (default: the newest launch number)."""
         key = (model, bucket)
         if key not in self.monitors:
             self.monitors[key] = StragglerMonitor(**self._straggler_kw)
-        self.monitors[key].record(self.launch_seq, dt)
+        self.monitors[key].record(self.launch_seq if seq is None else seq, dt)
 
     # -- drivers --------------------------------------------------------------
     def run(self, reqs: Optional[Sequence[ServeRequest]] = None,
@@ -599,7 +673,10 @@ class ControlPlane:
         return self.done
 
     def pending(self) -> int:
+        """Requests not yet answered: queued, or in a launch in flight
+        (plus one per active LM backend)."""
         n = sum(len(c) for q in self.queues.values() for c in q.values())
+        n += sum(len(st.reqs) for st in self._in_flight)
         n += sum(1 for be in self.backends.values()
                  if isinstance(be, LMBackend) and be.active())
         return n
@@ -650,6 +727,7 @@ class ControlPlane:
             "shed": len(self.shed),
             "queued": self.pending(),
             "replayed_requests": sum(1 for r in self.done if r.replays),
+            "launches_overlapped": self.launches_overlapped,
             "goodput_rps": (good / window if window else 0.0),
             "goodput_under_slo": ((good / self.submitted)
                                   if self.submitted else 1.0),

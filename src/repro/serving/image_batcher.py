@@ -42,11 +42,21 @@ sharded at init by the model's ``dist``-aware ``*_init``).
 Under the SLO-aware control plane (``serving/control_plane.py``) this
 batcher is no longer a peer entry point but the *launch engine* of an
 ``ImageBackend``: the control plane owns admission/priorities/deadlines
-and calls ``execute`` directly; ``rebind_dist`` is its elastic-degrade
-hook after replica loss.  ``execute`` brackets each step of a launch in a
-``jax.profiler.TraceAnnotation`` (``huge2.launch.stack``, ``.h2d``,
-``.dispatch``, ``.wait``, ``.d2h``) carrying the control plane's launch
-number, recorded only while a profiler traces the process.
+and drives launches directly; ``rebind_dist`` is its elastic-degrade
+hook after replica loss.
+
+**A launch in two halves.**  ``start`` stacks and pads the rows, puts the
+batch on the device, dispatches the bucket executable and asks for the
+output's copy to the host (``copy_to_host_async``), then returns an
+``InFlight`` handle without waiting; ``finish`` waits for that output and
+returns its live rows.  ``execute`` is ``finish(start(...))``: the
+batcher's own ``pump`` / ``run`` / ``drive_open_loop`` stay synchronous.
+The control plane keeps one launch in flight across a pump, so the
+device runs launch k+1 while launch k's answer lands on the host.  Each
+step is a ``jax.profiler.TraceAnnotation`` carrying the control plane's
+launch number, recorded only while a profiler traces the process:
+``huge2.launch.stack``, ``.h2d`` and ``.dispatch`` in ``start``,
+``huge2.launch.wait`` and ``.d2h`` in ``finish``.
 """
 from __future__ import annotations
 
@@ -78,6 +88,16 @@ class ImageRequest:
         if self.t_done is None or self.t_arrival is None:
             return None
         return self.t_done - self.t_arrival
+
+
+@dataclasses.dataclass
+class InFlight:
+    """A started launch: its device output (the whole padded bucket), the
+    live row count, the bucket and the control plane's launch number."""
+    out: jax.Array
+    live: int
+    bucket: int
+    seq: Optional[int] = None
 
 
 class DynamicImageBatcher:
@@ -261,16 +281,15 @@ class DynamicImageBatcher:
                     time.sleep(min(wait, 1e-3))
         return self.done
 
-    def execute(self, rows: Sequence[np.ndarray],
-                bucket: Optional[int] = None,
-                seq: Optional[int] = None) -> np.ndarray:
-        """Pad ``rows`` up to ``bucket`` and run ONE jitted launch,
-        returning the live output rows with no request bookkeeping — the
-        control plane's entry point (``serving.control_plane`` owns its
-        own queues and uses this batcher purely as the launch engine).
-        The launch is still recorded in ``launches`` so pad-fraction
-        stats cover both callers; ``seq`` (the control plane's
-        ``launch_seq``) labels the launch's trace spans."""
+    def start(self, rows: Sequence[np.ndarray],
+              bucket: Optional[int] = None,
+              seq: Optional[int] = None) -> InFlight:
+        """Pad ``rows`` up to ``bucket``, put the batch on the device and
+        dispatch ONE jitted launch without waiting for it; the output's
+        copy to the host is requested at once, so it can land while the
+        host does other work.  The launch is recorded in ``launches`` here
+        (pad-fraction stats cover every caller); ``seq`` (the control
+        plane's ``launch_seq``) labels the launch's trace spans."""
         bucket = self.bucket_for(len(rows)) if bucket is None else bucket
         with TraceAnnotation("huge2.launch.stack", seq=seq):
             batch = np.stack([np.asarray(r) for r in rows])
@@ -282,11 +301,23 @@ class DynamicImageBatcher:
             x = jax.device_put(batch)
         with TraceAnnotation("huge2.launch.dispatch", seq=seq):
             out = self._serve(x)
-        with TraceAnnotation("huge2.launch.wait", seq=seq):
-            out = jax.block_until_ready(out)
+            out.copy_to_host_async()
         self.launches.append((bucket, len(rows)))
-        with TraceAnnotation("huge2.launch.d2h", seq=seq):
-            return np.asarray(out)[:len(rows)]
+        return InFlight(out=out, live=len(rows), bucket=bucket, seq=seq)
+
+    def finish(self, launch: InFlight) -> np.ndarray:
+        """Wait for a started launch and return its live output rows."""
+        with TraceAnnotation("huge2.launch.wait", seq=launch.seq):
+            out = jax.block_until_ready(launch.out)
+        with TraceAnnotation("huge2.launch.d2h", seq=launch.seq):
+            return np.asarray(out)[:launch.live]
+
+    def execute(self, rows: Sequence[np.ndarray],
+                bucket: Optional[int] = None,
+                seq: Optional[int] = None) -> np.ndarray:
+        """One launch, start to finish: the live output rows of ``rows``
+        padded up to ``bucket``, with no request bookkeeping."""
+        return self.finish(self.start(rows, bucket, seq))
 
     def _launch(self, reqs: list[ImageRequest],
                 bucket: Optional[int] = None) -> list[ImageRequest]:

@@ -1,7 +1,9 @@
 """The serving path's own trace, on the CPU: one ``huge2.launch`` span per
-bucket launch with its five steps inside, a ``huge2.schedule`` span before
-it, the launch stamps on every request, answers unchanged by a running
-profiler, and the conv sites' names in the lowered generator."""
+bucket launch around its start (stack, H2D, dispatch), its finish (wait,
+D2H) found after it by ``seq``, also while launches overlap, a
+``huge2.schedule`` span before it, the launch stamps on every request,
+answers unchanged by a running profiler, and the conv sites' names in the
+lowered generator."""
 import json
 import pathlib
 import re
@@ -16,6 +18,7 @@ from repro.serving.control_plane import ControlPlane, ServeRequest
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 STEPS = ("huge2.launch.stack", "huge2.launch.h2d", "huge2.launch.dispatch",
          "huge2.launch.wait", "huge2.launch.d2h")
+START = STEPS[:3]                  # inside the launch span; the rest follow
 WAVES = (1, 3, 20, 2)              # requests per drained wave
 
 
@@ -55,18 +58,46 @@ def host_spans(log_dir) -> dict:
     return {k: sorted(v, key=lambda s: s[0]) for k, v in out.items()}
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def closed_loop(cp, pumps=12):
+    """Keep two of the largest bucket outstanding: each answer is followed
+    at once by a new request, so every pump starts a launch while the last
+    one is in flight."""
+    rng = np.random.default_rng(5)
+    rid = 0
+
+    def submit(n):
+        nonlocal rid
+        for _ in range(n):
+            cp.submit(ServeRequest(
+                rid=rid, model="m",
+                payload=rng.standard_normal(8).astype(np.float32)))
+            rid += 1
+    submit(32)
+    for _ in range(pumps):
+        submit(len(cp.pump()))
+    cp.run()
+
+
+def traced_run(log_dir, drive):
     cp, be = plane()
-    log_dir = tmp_path_factory.mktemp("trace")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(log_dir), profiler_options=opts)
     try:
-        answers = serve(cp)
+        answers = drive(cp)
     finally:
         jax.profiler.stop_trace()
     return cp, be, answers, host_spans(log_dir)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return traced_run(tmp_path_factory.mktemp("trace"), serve)
+
+
+@pytest.fixture(scope="module")
+def traced_overlap(tmp_path_factory):
+    return traced_run(tmp_path_factory.mktemp("trace_overlap"), closed_loop)
 
 
 def test_one_launch_span_per_launch(traced):
@@ -81,17 +112,38 @@ def test_one_launch_span_per_launch(traced):
     assert {b for b, _ in be.batcher.launches} == {1, 4, 16}
 
 
-def test_steps_nest_inside_their_launch(traced):
-    _, _, _, spans = traced
+def assert_steps_follow_their_launch(spans):
+    """Stack, H2D and dispatch lie inside their ``huge2.launch`` in order;
+    wait and D2H carry the same ``seq`` and follow its dispatch in order."""
     for s, e, a in spans["huge2.launch"]:
         t = s
         for name in STEPS:
             (step,) = [sp for sp in spans[name] if sp[2]["seq"] == a["seq"]]
-            assert t <= step[0] <= step[1] <= e, (name, a["seq"])
+            end = e if name in START else float("inf")
+            assert t <= step[0] <= step[1] <= end, (name, a["seq"])
             t = step[1]
         (sched,) = [sp for sp in spans["huge2.schedule"]
                     if sp[2]["seq"] == a["seq"]]
         assert sched[1] <= s
+
+
+def test_steps_nest_inside_their_launch(traced):
+    assert_steps_follow_their_launch(traced[3])
+
+
+def test_steps_found_by_seq_when_launches_overlap(traced_overlap):
+    cp, be, _, spans = traced_overlap
+    launches = spans["huge2.launch"]
+    assert len(launches) == len(be.batcher.launches) == cp.launch_seq
+    assert {b for b, _ in be.batcher.launches} == {16}
+    wait = {a["seq"]: s for s, _, a in spans["huge2.launch.wait"]}
+    # launch k+1 starts before launch k's wait does: they really overlap
+    overlapping = [a["seq"] for (s, _, a) in launches if s < wait.get(
+        a["seq"] - 1, -1)]
+    assert overlapping == list(range(2, cp.launch_seq + 1))
+    assert [a["overlapped"] for _, _, a in launches] == \
+        [0] + [1] * (cp.launch_seq - 1)
+    assert_steps_follow_their_launch(spans)
 
 
 def test_waits_match_the_request_stamps(traced):
@@ -122,7 +174,7 @@ def test_straggler_monitor_takes_the_launch_stamps(monkeypatch):
                             np.zeros((2,), np.float32), buckets=(4,))
     seen = []
     monkeypatch.setattr(cp, "_observe",
-                        lambda model, bucket, dt: seen.append(dt))
+                        lambda model, bucket, dt, seq=None: seen.append(dt))
     cp.run([ServeRequest(rid=i, model="m", payload=np.ones(2, np.float32))
             for i in range(3)])
     (dt,) = seen
